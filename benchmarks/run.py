@@ -13,6 +13,8 @@ def main():
     ap.add_argument("--only")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
     from . import (fig6_p2p, fig7_gnn_datasets, fig8_transformer_sweep,
                    fig9_pareto, roofline, sched_latency, serving_stream,
                    table3_accuracy, table4_improvement, table5_schedules)

@@ -9,11 +9,12 @@ Three questions a production deployment asks of the serving stack:
      from trough to saturation, with and without a mid-stream failure.
 
 All dispatch goes through the ExecutionBackend protocol; ``--backend
-pallas`` runs every batch on the real shard_map pipeline (interpret
-fallback on 1-device hosts) instead of the analytic model. Rows report the
-**overlap ratio** (pipeline busy-time / wall-time over the union of
-execution intervals, on the simulated clock): > 1.0 means the Engine had
-signature cells executing concurrently on disjoint device subsets. The
+pallas`` runs every batch on the real shard_map pipeline (a one-device
+chain of per-stage jits where fewer devices are visible) instead of the
+analytic model. Rows report the **overlap ratio** (pipeline busy-time /
+wall-time over the union of execution intervals, on the simulated
+clock): > 1.0 means the Engine had signature cells executing
+concurrently on disjoint device subsets. The
 ``diurnal-sync`` row replays the diurnal stream with blocking per-batch
 dispatch — by design its simulated-clock columns (latency, energy,
 overlap) are identical to the async row (the ordering-parity invariant);
@@ -651,6 +652,8 @@ if __name__ == "__main__":
     ap.add_argument("--backend", default="analytic",
                     choices=("analytic", "pallas"))
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         smoke(backend=args.backend)
     else:

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.core import (DynamicScheduler, PerfModel, Workload, KernelSpec,
                         paper_system)
+from repro.launch.mesh import make_mesh
 from repro.models.gnn import gcn_forward, init_gcn_params
 from repro.runtime import PipelineExecutor
 from repro.sparse import random_graph_csr, spmm_csr
@@ -40,7 +41,7 @@ def tiny_gcn_workload(v, e, feat, hidden=128, layers=2) -> Workload:
 
 def main():
     V, F, HID = 1024, 128, 128
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
 
     # 1) DYPE decides the stage partition from the data characteristics
     system = paper_system("pcie4")

@@ -26,6 +26,7 @@ import numpy as np
 from repro.checkpoint import Checkpointer
 from repro.configs import get_config
 from repro.data import TokenStream
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import axis_env_for_mesh, init_params, model_decls, param_count
 from repro.optim import AdamWConfig, opt_state_decls
@@ -56,7 +57,7 @@ def main():
     args = ap.parse_args()
 
     cfg, batch_size, seq = build(args.preset)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ax = axis_env_for_mesh(mesh)
     decls = model_decls(cfg, ax)
     print(f"[cfg] {cfg.name}-{args.preset}: "

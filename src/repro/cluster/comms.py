@@ -132,7 +132,15 @@ def mp_worker(wid: str, pool: dict, backend: str = "analytic",
               backend_kw: dict | None = None):
     """Spawn a real worker process serving the cluster protocol over a
     pipe. Returns ``(MpChannel, Process)``; send ``{"op": "stop"}`` (or
-    close the channel) and ``join()`` the process to shut down."""
+    close the channel) and ``join()`` the process to shut down.
+
+    The device backend (``pallas``) is refused: an accelerator belongs to
+    one process, and a parent that has touched JAX already holds it, so
+    the child would fail or hang. Serve it in-process (``LocalCluster``)."""
+    if backend == "pallas":
+        raise ValueError(
+            "mp_worker runs no device backend: the chip belongs to one "
+            "process; serve backend='pallas' in-process via LocalCluster")
     import multiprocessing as mp
 
     from .worker import worker_main

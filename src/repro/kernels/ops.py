@@ -2,9 +2,9 @@
 
 These take the model-zoo layouts ((B, S, H, D) activations, dense (M, K)
 sparse operands) and handle layout transposition + format conversion, so the
-rest of the framework never touches BlockSpecs. ``interpret=True`` (the
-default on CPU) runs the kernel bodies in Python for validation; on real TPU
-pass interpret=False.
+rest of the framework never touches BlockSpecs. They compile for the TPU by
+default; ``interpret=True`` runs the kernel bodies in Python instead, which
+is how the CPU tests validate them.
 """
 from __future__ import annotations
 
@@ -21,7 +21,7 @@ from .swa import swa_attention_pallas
 @functools.partial(jax.jit,
                    static_argnames=("window", "scale", "blk", "interpret"))
 def swa_attention_op(q, k, v, *, window: int, scale: float, blk: int = 128,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """Sliding-window attention, model layout: q (B,S,H,D), k/v (B,S,KV,D)."""
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
@@ -32,7 +32,7 @@ def swa_attention_op(q, k, v, *, window: int, scale: float, blk: int = 128,
 
 
 def spmm_op(a_dense: np.ndarray, x, *, bm: int = 128, bk: int = 128,
-            interpret: bool = True):
+            interpret: bool = False):
     """SpMM with host-side blocked-ELL conversion (one-time; the format is
     cached by callers for repeated multiplies, mirroring the paper's
     pre-loaded static graph data)."""

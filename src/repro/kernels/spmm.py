@@ -28,8 +28,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 # ---------------------------------------------------------------------------
 # format conversion (host-side, numpy)
@@ -62,14 +60,16 @@ def _spmm_kernel(idx_ref, a_ref, x_ref, o_ref):
 
     a = a_ref[0, 0]                                   # (bm, bk)
     x = x_ref[...]                                    # (bk, N)
+    # f32 operands contract at full f32 precision, narrower ones in one pass
+    prec = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
     o_ref[...] += jax.lax.dot(a.astype(jnp.float32),
-                              x.astype(jnp.float32),
+                              x.astype(jnp.float32), precision=prec,
                               preferred_element_type=jnp.float32
                               ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def spmm_blocked_ell(blocks, idx, x, *, interpret: bool = True):
+def spmm_blocked_ell(blocks, idx, x, *, interpret: bool = False):
     """(nbr, ell, bm, bk) blocked-ELL  @  (K, N) -> (M, N)."""
     nbr, ell, bm, bk = blocks.shape
     K, N = x.shape
@@ -87,7 +87,7 @@ def spmm_blocked_ell(blocks, idx, x, *, interpret: bool = True):
             out_specs=pl.BlockSpec((bm, N), lambda r, e, idx: (r, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((nbr * bm, N), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(idx, blocks, x)

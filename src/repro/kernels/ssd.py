@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, y_ref, s_out_ref,
                 s_ref, *, nc: int, Q: int):
@@ -37,31 +35,44 @@ def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, y_ref, s_out_ref,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
+    # f32 inputs contract at full f32 precision, narrower ones in one pass
+    prec = jax.lax.Precision.HIGHEST if x_ref.dtype == jnp.float32 else None
     x = x_ref[0, 0, 0].astype(jnp.float32)          # (Q, P)
     dt = dt_ref[0, 0, 0].astype(jnp.float32)        # (Q, 1)
     da = da_ref[0, 0, 0].astype(jnp.float32)        # (Q, 1)
     B = b_ref[0, 0].astype(jnp.float32)             # (Q, N)
     C = c_ref[0, 0].astype(jnp.float32)             # (Q, N)
 
-    la = jnp.cumsum(da, axis=0)                     # (Q, 1) log decay
-    seg = la - la.T                                 # (Q, Q): la_s - la_t
     iq = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     it = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    M = jnp.where(iq >= it, jnp.exp(seg), 0.0)
+    causal = iq >= it
+    # Mosaic has no cumsum: the inclusive prefix sum is a lower-triangular
+    # ones matmul, at full f32 precision like the cumsum it replaces
+    la = jax.lax.dot(causal.astype(jnp.float32), da,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)  # (Q, 1) log decay
+    seg = la - la.T                                 # (Q, Q): la_s - la_t
+    M = jnp.where(causal, jnp.exp(seg), 0.0)
 
-    cb = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
+    cb = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())), precision=prec,
                              preferred_element_type=jnp.float32)   # (Q,Q)
     W = cb * M * dt.T                               # ⊙ dt_t
-    y = jax.lax.dot(W, x, preferred_element_type=jnp.float32)      # (Q,P)
+    y = jax.lax.dot(W, x, precision=prec,
+                    preferred_element_type=jnp.float32)            # (Q,P)
 
     S = s_ref[...]                                  # (P, N)
     y = y + jax.lax.dot_general(C, S, (((1,), (1,)), ((), ())),
+                                precision=prec,
                                 preferred_element_type=jnp.float32
                                 ) * jnp.exp(la)
-    # state update: S' = e^{la_Q} S + (x ⊙ w)^T B, w = e^{la_Q - la} dt
-    w = jnp.exp(la[-1:] - la) * dt                  # (Q, 1)
-    s_ref[...] = (S * jnp.exp(la[-1]) +
+    # state update: S' = e^{la_Q} S + (x ⊙ w)^T B, w = e^{la_Q - la} dt.
+    # Static slices and a scalar decay: Mosaic lowers neither a negative
+    # index nor a (1, 1) -> (P, N) broadcast.
+    la_q = jax.lax.slice(la, (Q - 1, 0), (Q, 1))    # (1, 1)
+    w = jnp.exp(la_q - la) * dt                     # (Q, 1)
+    s_ref[...] = (S * jnp.exp(jnp.sum(da)) +
                   jax.lax.dot_general(x * w, B, (((0,), (0,)), ((), ())),
+                                      precision=prec,
                                       preferred_element_type=jnp.float32))
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
@@ -72,7 +83,7 @@ def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, y_ref, s_out_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_chunked_pallas(x, dt, B, C, A_log, D, *, chunk: int = 128,
-                       interpret: bool = True):
+                       interpret: bool = False):
     """Drop-in for models.ssm.ssd_chunked (zero init state).
 
     x: (b,L,H,P); dt: (b,L,H) raw (softplus applied here); B/C: (b,L,N).
@@ -115,7 +126,7 @@ def ssd_chunked_pallas(x, dt, B, C, A_log, D, *, chunk: int = 128,
             jax.ShapeDtypeStruct((b, H, Pd, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((Pd, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xq, dtq, daq, Bq, Cq)

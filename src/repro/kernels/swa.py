@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -45,11 +43,15 @@ def _swa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # f32 inputs contract at full f32 precision, narrower ones in one pass
+    prec = jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32 else None
+
     @pl.when(kb >= 0)
     def _step():
         q = q_ref[0, 0].astype(jnp.float32) * scale       # (blk, D)
         k = k_ref[0, 0].astype(jnp.float32)               # (blk, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=prec,
                                 preferred_element_type=jnp.float32)
         row = iq * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
         col = kb * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
@@ -65,7 +67,7 @@ def _swa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         p = jnp.where(valid, p, 0.0)
         v = v_ref[0, 0].astype(jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
+            p, v, precision=prec, preferred_element_type=jnp.float32)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
         m_ref[...] = m_new
 
@@ -79,7 +81,7 @@ def _swa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("window", "scale", "blk", "interpret"))
 def swa_attention_pallas(q, k, v, *, window: int, scale: float,
-                         blk: int = 128, interpret: bool = True):
+                         blk: int = 128, interpret: bool = False):
     """Banded flash attention. q: (B, H, S, D); k, v: (B, KV, S, D)."""
     B, H, S, D = q.shape
     KV = k.shape[1]
@@ -111,7 +113,7 @@ def swa_attention_pallas(q, k, v, *, window: int, scale: float,
             pltpu.VMEM((blk,), jnp.float32),
             pltpu.VMEM((blk, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -154,8 +154,9 @@ def parse_host_profiles(spec: str) -> dict:
     return out
 
 
-def run_stream(args) -> None:
-    """Serve a simulated traffic stream through the serving subsystem."""
+def run_stream(args):
+    """Serve a simulated traffic stream through the serving subsystem.
+    Returns ``(router, sim, snapshot)`` for callers that check the run."""
     from ..core import DynamicScheduler, PerfModel, paper_system
     from ..obs import (DashboardServer, FleetView, JsonlTraceSink, Tracer,
                        build_frame, dashboard_html, render_frame)
@@ -328,7 +329,7 @@ def run_stream(args) -> None:
           f"{wall:.1f}s wall")
     print(f"[serve] completed={snap.completed} dropped={snap.dropped} "
           f"thp={snap.throughput:.2f} req/s")
-    print(f"[serve] p50={snap.p50_latency*1e3:.1f}ms "
+    print(f"[serve] modelled (sim clock) p50={snap.p50_latency*1e3:.1f}ms "
           f"p99={snap.p99_latency*1e3:.1f}ms "
           f"energy/req={snap.energy_per_req:.2f}J "
           f"deadline_miss={snap.deadline_miss_rate:.1%}")
@@ -453,6 +454,7 @@ def run_stream(args) -> None:
             pass
         finally:
             server.close()
+    return router, sim, snap
 
 
 def run_decode(args) -> None:
@@ -467,10 +469,9 @@ def run_decode(args) -> None:
     from .steps import make_serve_step
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model")) if args.smoke else None
-    if mesh is None:
-        from .mesh import make_production_mesh
-        mesh = make_production_mesh()
+    from .mesh import make_mesh, make_production_mesh
+    mesh = (make_mesh((1, 1), ("data", "model")) if args.smoke
+            else make_production_mesh())
     ax = axis_env_for_mesh(mesh)
     params = init_params(model_decls(cfg, ax), jax.random.PRNGKey(0),
                          cfg.pdtype)
@@ -507,7 +508,8 @@ def run_decode(args) -> None:
     print("[serve] sample:", gen[0][:16].tolist())
 
 
-def main():
+def parse_args(argv=None):
+    """The CLI's arguments, validated (``argv`` defaults to sys.argv)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--stream", action="store_true",
                     help="streaming traffic mode (repro.serving)")
@@ -673,7 +675,7 @@ def main():
                     metavar="S",
                     help="append a cumulative MetricsSnapshot every S sim "
                          "seconds (0 = final snapshot only)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.no_preempt and not args.tenants:
         ap.error("--no-preempt requires --tenants")
     if args.replay_trace and args.trace_in:
@@ -720,12 +722,18 @@ def main():
             if args.true_host_profiles else {})
     except ValueError as e:
         ap.error(str(e))
+    if not args.stream and not args.arch:
+        ap.error("--arch is required unless --stream is given")
+    return args
 
+
+def main():
+    args = parse_args()
+    from .compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
     if args.stream:
         run_stream(args)
     else:
-        if not args.arch:
-            ap.error("--arch is required unless --stream is given")
         run_decode(args)
 
 

@@ -38,10 +38,9 @@ def main():
     from .steps import make_train_step
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model")) if args.smoke else None
-    if mesh is None:
-        from .mesh import make_production_mesh
-        mesh = make_production_mesh()
+    from .mesh import make_mesh, make_production_mesh
+    mesh = (make_mesh((1, 1), ("data", "model")) if args.smoke
+            else make_production_mesh())
     ax = axis_env_for_mesh(mesh)
     decls = model_decls(cfg, ax)
     print(f"[train] {cfg.name}{' (smoke)' if args.smoke else ''}: "

@@ -154,7 +154,6 @@ def chunked_softmax_xent(hidden, labels, mask, p, cfg: ModelConfig, *,
             w, jax.sharding.NamedSharding(mesh, P(None, ax.model)))
 
     def one_vp(h_c, y_c, m_c):
-        from jax.experimental.shard_map import shard_map
         v_loc = V // tp
         bspec = ax.dp if dp > 1 else None
 
@@ -184,11 +183,11 @@ def chunked_softmax_xent(hidden, labels, mask, p, cfg: ModelConfig, *,
                 cnt = jax.lax.psum(cnt, ax.dp)
             return loss, cnt
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(bspec, None, None), P(None, ax.model),
                       P(bspec, None), P(bspec, None)),
-            out_specs=(P(), P()), check_rep=False)(h_c, w, y_c, m_c)
+            out_specs=(P(), P()), check_vma=False)(h_c, w, y_c, m_c)
 
     def one(h_c, y_c, m_c):
         if use_vp:

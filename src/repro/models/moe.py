@@ -23,16 +23,6 @@ from .common import AxisEnv, ModelConfig, ParamDecl, fsdp_spec
 from .layers import _gate
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    except (TypeError, AttributeError):  # older API
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
-
-
 def moe_decls(cfg: ModelConfig, ax: AxisEnv, stack: int | None = None):
     d, E, ff = cfg.d_model, cfg.n_experts, cfg.d_ff_expert
     st = () if stack is None else (stack,)
@@ -140,10 +130,10 @@ def moe_ffn(p, x, cfg: ModelConfig, ax: AxisEnv, mesh):
                 ax.dp if fsdp_gather else None)
     body = functools.partial(_local_expert_ffn, cfg=cfg, ax=ax, ep=ep,
                              fsdp_gather=fsdp_gather)
-    routed, aux = shard_map_compat(
-        body, mesh,
+    routed, aux = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(ax.dp, None, None), P(None, None), wi_spec, wo_spec),
-        out_specs=(P(ax.dp, None, None), P()),
+        out_specs=(P(ax.dp, None, None), P()), check_vma=False,
     )(x, p["router"], p["wi"], p["wo"])
     if cfg.n_shared_experts:
         h = jnp.einsum("bsd,df->bsf", x, p["shared_wi"].astype(cfg.cdtype))

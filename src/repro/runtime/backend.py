@@ -35,9 +35,10 @@ Three implementations ship:
     device counts) and actually runs the microbatches; completion *times*
     still come from the schedule model so
     the simulated clock stays consistent, which is also what makes analytic
-    vs pallas completion ordering bit-identical (the parity tests). Falls
-    back to an in-process interpret chain when the host exposes fewer
-    devices than the pipeline has stages, so tier-1 tests run hostless.
+    vs pallas completion ordering bit-identical (the parity tests). Where
+    the host exposes fewer devices than the DP's stage groups need (one
+    chip, or the CPU tests) it runs the same stage chain sequentially on
+    one device instead; the handle's ``mode`` says which it got.
   * ``ReplayBackend`` — deterministic timings from recorded traces
     (``TraceRecorder`` wraps any backend and captures them), for replaying
     production behavior in tests and what-if studies.
@@ -51,6 +52,7 @@ elapsed wall-clock for backends that execute actual compute.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import json
 import time
@@ -82,12 +84,15 @@ class PipelineHandle:
     """A deployed schedule: everything a backend needs to run batches under
     it. ``epoch`` is the DynamicScheduler epoch at prepare time — a resize
     or objective flip bumps the scheduler's epoch, invalidating the handle
-    (holders compare and re-prepare)."""
+    (holders compare and re-prepare). ``mode`` is how the backend chose to
+    run it, for backends with more than one way ("mesh" or "chain" on
+    pallas; empty elsewhere)."""
     schedule: ScheduleResult
     workload: Workload
     epoch: int = 0
     backend: str = ""
     payload: object = None         # backend-specific resident state
+    mode: str = ""
 
     def stale(self, current_epoch: int) -> bool:
         return self.epoch != current_epoch
@@ -272,9 +277,14 @@ class PallasPipelineBackend(ExecutionBackend):
     count, activations handed over at group boundaries.
 
     ``mode``:
-      * "mesh"      — require a (sum of DP stage counts,) jax mesh
-      * "interpret" — run the same stage chain sequentially on one device
-      * "auto"      — mesh when enough devices are visible, else interpret
+      * "mesh"  — a (sum of DP stage counts,) jax mesh; raises when fewer
+                  devices are visible
+      * "chain" — the same stage chain as per-stage jits, sequential on
+                  the default device
+      * "auto"  — mesh when enough devices are visible, else chain
+
+    ``output_platforms`` counts executed batches by the platform of the
+    device their output landed on ("tpu", "cpu", ...).
 
     Measured stage times are real wall seconds (``measured_sim_clock`` is
     False): they are NOT comparable to the schedule's simulated-seconds
@@ -290,14 +300,16 @@ class PallasPipelineBackend(ExecutionBackend):
 
     def __init__(self, *, act_batch: int = 8, act_dim: int = 16,
                  max_micro: int = 8, mode: str = "auto"):
-        assert mode in ("auto", "mesh", "interpret"), mode
+        assert mode in ("auto", "mesh", "chain"), mode
         self.act_batch = act_batch
         self.act_dim = act_dim
         self.max_micro = max_micro
         self.mode = mode
-        # prepared payloads are pure functions of the stage-kind structure,
-        # so cell evictions/readmissions don't pay the jit cost twice
-        self._payload_cache: dict = {}
+        self.output_platforms: collections.Counter = collections.Counter()
+        # (mode, payload) by (stage kinds, group sizes): a pure function of
+        # the stage structure, so cell evictions/readmissions don't pay
+        # the jit cost twice
+        self.prepared: dict = {}
 
     # -- stage lowering ------------------------------------------------------
     def _stage_fn(self, kinds):
@@ -321,6 +333,7 @@ class PallasPipelineBackend(ExecutionBackend):
     def prepare(self, schedule, workload, *, epoch: int = 0) -> PipelineHandle:
         import jax
         import jax.numpy as jnp
+        import numpy as np
 
         stages = schedule.pipeline.stages
         n_stages = len(stages)
@@ -330,10 +343,12 @@ class PallasPipelineBackend(ExecutionBackend):
                                   for i in range(s.i0, s.i1))
                             for s in stages)
         cache_key = (stage_kinds, group_sizes)
-        cached = self._payload_cache.get(cache_key)
+        cached = self.prepared.get(cache_key)
         if cached is not None:
+            mode, payload = cached
             return PipelineHandle(schedule, workload, epoch=epoch,
-                                  backend=self.name, payload=cached)
+                                  backend=self.name, payload=payload,
+                                  mode=mode)
         fns = [self._stage_fn(kinds) for kinds in stage_kinds]
         # per-stage weight: scaled identity + deterministic off-diagonal so
         # stage order matters (parity/permutations are observable)
@@ -345,33 +360,39 @@ class PallasPipelineBackend(ExecutionBackend):
         params = {"w": ws}
 
         n_dev = sum(group_sizes)
-        use_mesh = self.mode == "mesh" or (
-            self.mode == "auto"
-            and n_stages > 1 and len(jax.devices()) >= n_dev)
-        if use_mesh:
+        devices = jax.devices()
+        if self.mode == "mesh" and len(devices) < n_dev:
+            raise RuntimeError(
+                f"mode='mesh' needs {n_dev} devices for stage groups "
+                f"{group_sizes}, but only {len(devices)} are visible")
+        if self.mode == "mesh" or (self.mode == "auto" and n_stages > 1
+                                   and len(devices) >= n_dev):
             from .pipeline_exec import GroupedPipelineExecutor
-            mesh = jax.make_mesh((n_dev,), ("stage",))
-            runner = GroupedPipelineExecutor(mesh, "stage", fns, params,
-                                             (self.act_batch, F),
-                                             group_sizes)
-            payload = ("mesh", runner)
+            # a plain Mesh has Auto axes: the executor's host-side gather
+            # of the last head's slice is refused under Explicit ones
+            mesh = jax.sharding.Mesh(np.asarray(devices[:n_dev]),
+                                     ("stage",))
+            mode = "mesh"
+            payload = GroupedPipelineExecutor(mesh, "stage", fns, params,
+                                              (self.act_batch, F),
+                                              group_sizes)
         else:
-            # interpret fallback: the same stage chain, sequential on one
-            # device — identical math to the executor's per-microbatch path,
-            # but jitted per stage so the stage loop can be timed stage by
-            # stage (the measured times the straggler monitors consume)
+            # the same stage chain, sequential on one device — identical
+            # math to the executor's per-microbatch path, but jitted per
+            # stage so the stage loop can be timed stage by stage (the
+            # measured times the straggler monitors consume)
             def stage_apply(fn):
                 def apply(w, micro):
                     return jax.vmap(lambda x: fn({"w": w}, x))(micro)
                 return jax.jit(apply)
 
-            payload = ("interpret", tuple(stage_apply(f) for f in fns),
-                       params)
-        self._payload_cache[cache_key] = payload
+            mode = "chain"
+            payload = (tuple(stage_apply(f) for f in fns), params)
+        self.prepared[cache_key] = (mode, payload)
         return PipelineHandle(schedule, workload, epoch=epoch,
-                              backend=self.name, payload=payload)
+                              backend=self.name, payload=payload, mode=mode)
 
-    def _micro(self, n_micro: int):
+    def microbatches(self, n_micro: int):
         """Deterministic microbatch content (replayable, seedless)."""
         import jax.numpy as jnp
         import numpy as np
@@ -383,6 +404,20 @@ class PallasPipelineBackend(ExecutionBackend):
                         dtype=np.float32)
             .reshape(m, self.act_batch, self.act_dim))
 
+    def dispatch(self, handle, micro) -> tuple:
+        """Enqueue ``micro`` (m, act_batch, act_dim) through the handle's
+        stages without blocking. Returns the device arrays to wait on, in
+        completion order; the last is the pipeline output (m, B, F)."""
+        if handle.mode == "mesh":
+            return (handle.payload(micro),)
+        stage_jits, params = handle.payload
+        outs = []
+        x = micro
+        for s, sj in enumerate(stage_jits):
+            x = sj(params["w"][s], x)
+            outs.append(x)
+        return tuple(outs)
+
     def submit(self, handle, batch, t0: float) -> BackendFuture:
         """Dispatch the batch to the device WITHOUT blocking (jax dispatch
         is asynchronous) and return a future. Completion *times* still come
@@ -392,45 +427,35 @@ class PallasPipelineBackend(ExecutionBackend):
         immediately; ``result()`` blocks on the device and fills in the
         measured wall/stage seconds.
 
-        Measured per-stage times: in interpret mode each stage is a
-        separate jit call, so blocking on the successive stage outputs in
-        order timestamps each stage's real completion (the device executes
-        them in dispatch order). In mesh mode the whole pipeline is one
+        Measured per-stage times: in chain mode each stage is a separate
+        jit call, so blocking on the successive stage outputs in order
+        timestamps each stage's real completion (the device executes them
+        in dispatch order). In mesh mode the whole pipeline is one
         shard_map program, so the measured wall is apportioned over stages
         by the schedule's stage weights — total is measured, the split is
         modeled."""
         n = batch_size(batch)
         base = _analytic_report(handle.schedule, n, t0)
-        micro = self._micro(n)             # host-side input build: not timed
+        micro = self.microbatches(n)       # host-side input build: not timed
         w0 = time.perf_counter()
-        if handle.payload[0] == "mesh":
-            out = handle.payload[1](micro)     # async dispatch
+        outs = self.dispatch(handle, micro)
 
-            def resolve():
-                out.block_until_ready()
-                wall = time.perf_counter() - w0
+        def resolve():
+            meas, prev = [], w0
+            for o in outs:                 # device runs stages in order
+                o.block_until_ready()
+                now = time.perf_counter()
+                meas.append(now - prev)
+                prev = now
+            self.output_platforms.update(
+                {d.platform for d in outs[-1].devices()})
+            wall = prev - w0
+            if handle.mode == "mesh":
                 est = base.stage_times
                 tot = sum(est) or 1.0
-                return dataclasses.replace(
-                    base, wall=wall,
-                    measured_stage_times=tuple(wall * e / tot for e in est))
-        else:
-            _, stage_jits, params = handle.payload
-            outs = []
-            x = micro
-            for s, sj in enumerate(stage_jits):   # async per-stage dispatch
-                x = sj(params["w"][s], x)
-                outs.append(x)
-
-            def resolve():
-                meas, prev = [], w0
-                for o in outs:                 # device runs stages in order
-                    o.block_until_ready()
-                    now = time.perf_counter()
-                    meas.append(now - prev)
-                    prev = now
-                return dataclasses.replace(
-                    base, wall=prev - w0, measured_stage_times=tuple(meas))
+                meas = [wall * e / tot for e in est]
+            return dataclasses.replace(
+                base, wall=wall, measured_stage_times=tuple(meas))
         return BackendFuture(t0, base.finishes, resolve)
 
     def execute(self, handle, batch, t0: float) -> CompletionReport:

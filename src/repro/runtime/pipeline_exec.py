@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_round_count(n_micro: int, n_stages: int) -> int:
@@ -65,10 +64,10 @@ class PipelineExecutor:
         pspec_params = jax.tree.map(lambda _: P(axis), self.params)
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(pspec_params, P()),          # params sharded by stage,
             out_specs=P(axis),                     # microbatches replicated
-            check_rep=False)
+            check_vma=False)
         def run(params, micro):
             # params leaves: (1, ...) local stage slice; micro: (m, B, F)
             sid = jax.lax.axis_index(axis)
@@ -166,10 +165,10 @@ class GroupedPipelineExecutor:
         handover = [(heads[s], heads[s + 1]) for s in range(n_stages - 1)]
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(), P()),                  # params + micro replicated
             out_specs=P(axis),
-            check_rep=False)
+            check_vma=False)
         def run(params, micro):
             did = jax.lax.axis_index(axis)
             sid = dev_stage[did]

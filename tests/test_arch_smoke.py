@@ -7,13 +7,14 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS, SHAPES, LONG_SKIP, get_config, get_smoke
+from repro.launch.mesh import make_mesh
 from repro.models import (axis_env_for_mesh, decode_step, init_cache,
                           init_params, lm_loss, model_decls, param_count)
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _batch(cfg, key, B=2, S=128):
@@ -87,7 +88,7 @@ def test_cells_cover_40():
 
 def test_smoke_param_counts_small():
     """Smoke configs stay CPU-sized (<60M params)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ax = axis_env_for_mesh(mesh)
     for arch in ARCHS:
         decls = model_decls(get_smoke(arch), ax)

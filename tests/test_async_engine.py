@@ -68,7 +68,7 @@ def test_pallas_future_is_two_phase():
     known immediately, measured wall/stage seconds only after result()."""
     dyn = fresh_dyn()
     res = dyn.submit(WL_A)
-    be = PallasPipelineBackend(mode="interpret", act_dim=4, act_batch=2)
+    be = PallasPipelineBackend(mode="chain", act_dim=4, act_batch=2)
     h = be.prepare(res, WL_A, epoch=dyn.epoch)
     fut = be.submit(h, 3, 5.0)
     assert not fut.done()
@@ -93,7 +93,7 @@ def test_wall_clock_measurements_never_feed_monitors():
     demotion, no matter how slow the host was."""
     assert PallasPipelineBackend.measured_sim_clock is False
     assert AnalyticBackend.measured_sim_clock is True
-    be = PallasPipelineBackend(mode="interpret", act_dim=4, act_batch=2)
+    be = PallasPipelineBackend(mode="chain", act_dim=4, act_batch=2)
     r = fresh_router(backend=be)
     for i in range(4):
         r.submit(Request(i, WL_A, 0.0), 0.0)
@@ -111,7 +111,7 @@ def test_trace_recorder_on_wall_clock_backend_stays_sim_clock():
     simulated seconds — the model stage times are recorded instead."""
     dyn = fresh_dyn()
     rec = TraceRecorder(
-        PallasPipelineBackend(mode="interpret", act_dim=4, act_batch=2))
+        PallasPipelineBackend(mode="chain", act_dim=4, act_batch=2))
     assert rec.measured_sim_clock is False
     res = dyn.submit(WL_A)
     h = rec.prepare(res, WL_A, epoch=dyn.epoch)

@@ -64,7 +64,7 @@ def test_make_backend_factory():
 
 
 # ---------------------------------------------------------------------------
-# acceptance: analytic vs pallas (interpret) completion-ordering parity
+# acceptance: analytic vs pallas (chain) completion-ordering parity
 # ---------------------------------------------------------------------------
 def _stream_finishes(backend):
     """Run the same batch stream through ``backend``; returns the stream's
@@ -86,23 +86,37 @@ def _stream_finishes(backend):
 def test_analytic_pallas_ordering_parity():
     order_a, fin_a = _stream_finishes(AnalyticBackend())
     order_p, fin_p = _stream_finishes(
-        PallasPipelineBackend(mode="interpret", act_dim=4, act_batch=2))
+        PallasPipelineBackend(mode="chain", act_dim=4, act_batch=2))
     assert order_a == order_p
-    # interpret-mode times come from the same schedule model: bit-identical
+    # chain-mode times come from the same schedule model: bit-identical
     assert fin_a == fin_p
 
 
 def test_pallas_backend_actually_executes():
     dyn = fresh_dyn()
     res = dyn.submit(WL_A)
-    be = PallasPipelineBackend(mode="interpret", act_dim=4, act_batch=2)
+    be = PallasPipelineBackend(mode="chain", act_dim=4, act_batch=2)
     h = be.prepare(res, WL_A, epoch=dyn.epoch)
+    assert h.mode == "chain"
     rep = be.execute(h, 3, 0.0)
     assert rep.wall > 0.0                    # real compute happened
     assert len(rep.finishes) == 3
+    assert be.output_platforms == {"cpu": 1}
     # prepared payloads are cached by stage structure
     h2 = be.prepare(res, WL_A, epoch=dyn.epoch)
     assert h2.payload is h.payload
+
+
+def test_pallas_auto_mode_reports_chain_and_mesh_mode_refuses():
+    """On one device ``auto`` records the sequential chain on the handle;
+    ``mesh`` with too few devices raises instead of degrading."""
+    dyn = fresh_dyn()
+    res = dyn.submit(WL_A)
+    auto = PallasPipelineBackend(act_dim=4, act_batch=2)
+    assert auto.prepare(res, WL_A).mode == "chain"
+    with pytest.raises(RuntimeError, match="needs"):
+        PallasPipelineBackend(mode="mesh", act_dim=4,
+                              act_batch=2).prepare(res, WL_A)
 
 
 def test_router_parity_analytic_vs_pallas():
@@ -118,7 +132,7 @@ def test_router_parity_analytic_vs_pallas():
         sim.run(r)
         return sorted(r.metrics.latencies), r.metrics.completed
     a = run(AnalyticBackend())
-    p = run(PallasPipelineBackend(mode="interpret", act_dim=4, act_batch=2))
+    p = run(PallasPipelineBackend(mode="chain", act_dim=4, act_batch=2))
     assert a == p
 
 

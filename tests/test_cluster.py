@@ -92,6 +92,13 @@ def test_mp_transport_smoke_roundtrip():
     assert proc.exitcode == 0
 
 
+def test_mp_worker_refuses_device_backend():
+    """The chip belongs to one process: a spawned worker must not try to
+    build the device backend while its parent may hold the chip."""
+    with pytest.raises(ValueError, match="in-process"):
+        mp_worker("mp0", {"FPGA": 3, "GPU": 2}, backend="pallas")
+
+
 # ---------------------------------------------------------------------------
 # worker core + controller basics
 # ---------------------------------------------------------------------------

@@ -79,8 +79,9 @@ def test_pipeline_executor_multi_device():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import sys; sys.path.insert(0, r"%s")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.runtime import PipelineExecutor
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh((4,), ("stage",))
         rng = np.random.default_rng(0)
         Ws = jnp.asarray(rng.normal(size=(4, 16, 16)).astype(np.float32) * 0.1)
         ex = PipelineExecutor(mesh, "stage",
@@ -109,8 +110,9 @@ def test_grouped_pipeline_executor_multi_device():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import sys; sys.path.insert(0, r"%s")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.runtime import GroupedPipelineExecutor
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh((4,), ("stage",))
         rng = np.random.default_rng(0)
         Ws = jnp.asarray(rng.normal(size=(3, 16, 16)).astype(np.float32) * 0.1)
         ex = GroupedPipelineExecutor(
@@ -147,8 +149,8 @@ def test_pallas_backend_mesh_mode_multi_device():
         res = dyn.submit(wl)
         be = PallasPipelineBackend(mode="mesh", act_dim=4, act_batch=2)
         h = be.prepare(res, wl, epoch=dyn.epoch)
-        kind, runner = h.payload
-        assert kind == "mesh", kind
+        assert h.mode == "mesh", h.mode
+        runner = h.payload
         assert runner.group_sizes == tuple(
             s.n for s in res.pipeline.stages), runner.group_sizes
         rep = be.execute(h, 3, 0.0)

@@ -24,7 +24,7 @@ def test_swa_shapes(S, window, blk, D):
     k = jax.random.normal(ks[1], (B, KV, S, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, KV, S, D), jnp.float32)
     out = swa_attention_pallas(q, k, v, window=window,
-                               scale=D ** -0.5, blk=blk)
+                               scale=D ** -0.5, blk=blk, interpret=True)
     exp = ref.swa_attention_ref(q, k, v, window=window, scale=D ** -0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                atol=2e-5, rtol=2e-5)
@@ -37,7 +37,8 @@ def test_swa_dtypes(dtype):
     q = jax.random.normal(ks[0], (B, H, S, D)).astype(dtype)
     k = jax.random.normal(ks[1], (B, KV, S, D)).astype(dtype)
     v = jax.random.normal(ks[2], (B, KV, S, D)).astype(dtype)
-    out = swa_attention_pallas(q, k, v, window=W, scale=0.125)
+    out = swa_attention_pallas(q, k, v, window=W, scale=0.125,
+                               interpret=True)
     exp = ref.swa_attention_ref(q, k, v, window=W, scale=0.125)
     assert out.dtype == dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -52,7 +53,8 @@ def test_swa_gqa_groups():
     q = jax.random.normal(ks[0], (B, H, S, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, KV, S, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, KV, S, D), jnp.float32)
-    out = swa_attention_pallas(q, k, v, window=W, scale=0.125)
+    out = swa_attention_pallas(q, k, v, window=W, scale=0.125,
+                               interpret=True)
     exp = ref.swa_attention_ref(q, k, v, window=W, scale=0.125)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5)
 
@@ -65,7 +67,7 @@ def test_swa_matches_model_zoo_semantics():
     q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, S, KV, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, S, KV, D), jnp.float32)
-    out = swa_attention_op(q, k, v, window=W, scale=0.125)
+    out = swa_attention_op(q, k, v, window=W, scale=0.125, interpret=True)
     exp = swa_attention(q, k, v, window=W, scale=0.125)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                atol=3e-5, rtol=3e-5)
@@ -78,7 +80,8 @@ def test_swa_window_larger_than_kvblocks_clamps():
     q = jax.random.normal(ks[0], (B, H, S, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, KV, S, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, KV, S, D), jnp.float32)
-    out = swa_attention_pallas(q, k, v, window=256, scale=0.125)
+    out = swa_attention_pallas(q, k, v, window=256, scale=0.125,
+                               interpret=True)
     exp = ref.swa_attention_ref(q, k, v, window=256, scale=0.125)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5)
 
@@ -97,7 +100,7 @@ def test_spmm_shapes(M, K, N, density):
     blocks, idx = to_blocked_ell(a, 128, 128)
     x = rng.normal(size=(K, N)).astype(np.float32)
     out = np.asarray(spmm_blocked_ell(jnp.asarray(blocks), jnp.asarray(idx),
-                                      jnp.asarray(x)))
+                                      jnp.asarray(x), interpret=True))
     exp = a.astype(np.float64) @ x.astype(np.float64)
     np.testing.assert_allclose(out, exp, atol=1e-3, rtol=1e-4)
 
@@ -125,7 +128,7 @@ def test_spmm_empty_rows():
     blocks, idx = to_blocked_ell(a, 128, 128)
     x = np.ones((256, 64), np.float32)
     out = np.asarray(spmm_blocked_ell(jnp.asarray(blocks), jnp.asarray(idx),
-                                      jnp.asarray(x)))
+                                      jnp.asarray(x), interpret=True))
     assert np.all(out[:128] == 0)
     np.testing.assert_allclose(out[200], 3.0)
 
@@ -138,7 +141,8 @@ def test_spmm_matches_csr_substrate():
     dense = csr_to_dense(g)
     blocks, idx = to_blocked_ell(dense, 128, 128)
     out_k = np.asarray(spmm_blocked_ell(jnp.asarray(blocks),
-                                        jnp.asarray(idx), x))
+                                        jnp.asarray(idx), x,
+                                        interpret=True))
     out_c = np.asarray(spmm_csr(g, x))
     np.testing.assert_allclose(out_k, out_c, atol=1e-4, rtol=1e-4)
 
@@ -164,7 +168,8 @@ def test_ssd_shapes(L, Q, P, N):
     from repro.models.ssm import ssd_chunked
     x, dt, B, C, A_log, D = _ssd_inputs(jax.random.PRNGKey(L + P), 2, L, 2,
                                         P, N)
-    y1, s1 = ssd_chunked_pallas(x, dt, B, C, A_log, D, chunk=Q)
+    y1, s1 = ssd_chunked_pallas(x, dt, B, C, A_log, D, chunk=Q,
+                                interpret=True)
     y2, s2 = ssd_chunked(x, dt, B, C, A_log, D, chunk=Q)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                atol=2e-5, rtol=2e-5)
@@ -178,7 +183,46 @@ def test_ssd_state_feeds_decode():
     from repro.models.ssm import ssd_chunked
     x, dt, B, C, A_log, D = _ssd_inputs(jax.random.PRNGKey(9), 1, 256, 2,
                                         64, 128)
-    _, s_k = ssd_chunked_pallas(x, dt, B, C, A_log, D, chunk=128)
+    _, s_k = ssd_chunked_pallas(x, dt, B, C, A_log, D, chunk=128,
+                                interpret=True)
     _, s_r = ssd_chunked(x, dt, B, C, A_log, D, chunk=64)  # different chunking
     np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_r),
                                atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# contraction precision: each kernel chooses its own, whatever the caller's
+# default (on a TPU the default contracts f32 operands in one bf16 pass)
+# ---------------------------------------------------------------------------
+def _kernel_jaxpr(name, dtype):
+    from repro.kernels.ssd import ssd_chunked_pallas
+    z = lambda *shape, dt=dtype: jnp.zeros(shape, dt)  # noqa: E731
+    if name == "swa":
+        fn = lambda q: swa_attention_pallas(  # noqa: E731
+            q, q, q, window=128, scale=0.125, interpret=True)
+        args = (z(1, 1, 256, 64),)
+    elif name == "spmm":
+        fn = lambda b, i, x: spmm_blocked_ell(b, i, x,  # noqa: E731
+                                              interpret=True)
+        args = (z(2, 2, 128, 128), z(2, 2, dt=jnp.int32), z(256, 128))
+    else:
+        fn = lambda *a: ssd_chunked_pallas(*a, chunk=128,  # noqa: E731
+                                           interpret=True)
+        args = (z(1, 256, 2, 64), z(1, 256, 2), z(1, 256, 128),
+                z(1, 256, 128), z(2, dt=jnp.float32), z(2, dt=jnp.float32))
+    return str(jax.make_jaxpr(fn)(*args))
+
+
+@pytest.mark.parametrize("name", ["swa", "spmm", "ssd"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_kernel_contraction_precision(name, dtype):
+    """f32 inputs contract at HIGHEST in every dot; narrower inputs take the
+    default, except SSD's prefix-sum matmul, which stands for a cumsum."""
+    text = _kernel_jaxpr(name, dtype)
+    dots = text.count("dot_general[")
+    highest = text.count("precision=(Precision.HIGHEST")
+    assert dots > 0
+    if dtype == jnp.float32:
+        assert highest == dots
+    else:
+        assert highest == (1 if name == "ssd" else 0)

@@ -10,6 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
+
 REPO = Path(__file__).resolve().parent.parent
 
 
@@ -59,7 +61,7 @@ def test_quantized_decode_matches_fp():
     from repro.models.quant import QuantizedArray, quantize_params
     cfg = get_smoke("mistral-large-123b").replace(
         d_model=256, d_ff=512, n_heads=4, n_kv_heads=2, head_dim=64)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ax = axis_env_for_mesh(mesh)
     params = init_params(model_decls(cfg, ax), jax.random.PRNGKey(0),
                          cfg.pdtype)
@@ -91,8 +93,9 @@ def test_vocab_parallel_loss_matches_baseline():
         from repro.configs import get_smoke
         from repro.models import (axis_env_for_mesh, init_params,
                                   model_decls, lm_loss)
+        from repro.launch.mesh import make_mesh
         cfg = get_smoke("gemma-2b").replace(vocab_size=512)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         ax = axis_env_for_mesh(mesh)
         params = init_params(model_decls(cfg, ax), jax.random.PRNGKey(0),
                              cfg.pdtype)
@@ -126,7 +129,7 @@ def test_moe_decode_small_capacity_still_correct():
     from repro.models import (axis_env_for_mesh, decode_step, init_cache,
                               init_params, model_decls)
     cfg = get_smoke("deepseek-v3-671b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ax = axis_env_for_mesh(mesh)
     params = init_params(model_decls(cfg, ax), jax.random.PRNGKey(0),
                          cfg.pdtype)
