@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import math
 
+from ..obs.trace import NULL_TRACER
 from .device import SystemSpec
 from .perf_model import PerfModel
 from .scheduler import ScheduleResult, Scheduler
@@ -75,6 +76,9 @@ class DynamicScheduler:
         # the balanced-mode frontier walk at that fraction instead of the
         # global binary mode; 1.0 == the perf endpoint.
         self.targets: dict = {}
+        # span bus (repro.obs): times each DP solve (a cache miss); the
+        # serving Engine hands its tracer on here
+        self.tracer = NULL_TRACER
 
     def _scheduler_for(self, pool, host=None):
         """Scheduler on the full system (pool=None) or on a per-pool-count
@@ -120,12 +124,14 @@ class DynamicScheduler:
     def _lookup(self, wl, sig, pool, host=None):
         res = self._cache.get(sig)
         if res is None:
-            sel = sig[1]
-            sched = self._scheduler_for(pool, host)
-            if isinstance(sel, tuple):          # ("op", frac)
-                res = sched.schedule(wl, "balanced", balanced_frac=sel[1])
-            else:
-                res = sched.schedule(wl, sel)
+            with self.tracer.span("dp", "dp.solve", 0.0):
+                sel = sig[1]
+                sched = self._scheduler_for(pool, host)
+                if isinstance(sel, tuple):          # ("op", frac)
+                    res = sched.schedule(wl, "balanced",
+                                         balanced_frac=sel[1])
+                else:
+                    res = sched.schedule(wl, sel)
             self._cache[sig] = res
             self.dp_solves += 1
         return res

@@ -21,17 +21,21 @@ Spans are **derived, never inputs**: nothing in the control path reads
 tracer state, so record/replay determinism is untouched (tests assert a
 steal-heavy cluster run replays byte-identically with tracing on). The
 disabled ``NULL_TRACER`` costs one attribute check per publish site.
+Duration spans (``Tracer.span``) time the host work of Router, Engine,
+the DP and the pallas backend; ``Tracer(profile=True)`` puts them on the
+``jax.profiler`` host plane as ``dype:<name>`` annotations.
 See docs/observability.md for the span schema and a walkthrough.
 """
-from .trace import (JsonlTraceSink, MemorySink, NULL_TRACER, Tracer,
-                    TraceSink)
+from .trace import (JsonlTraceSink, MemorySink, NULL_SPAN, NULL_TRACER,
+                    PROFILE_PREFIX, Tracer, TraceSink)
 from .schema import REQUEST_CHAIN, REQUIRED_KEYS, read_jsonl, validate
 from .fleet import FleetView
 from .dashboard import (DashboardServer, build_frame, dashboard_html,
                         render_frame)
 
 __all__ = [
-    "JsonlTraceSink", "MemorySink", "NULL_TRACER", "Tracer", "TraceSink",
+    "JsonlTraceSink", "MemorySink", "NULL_SPAN", "NULL_TRACER",
+    "PROFILE_PREFIX", "Tracer", "TraceSink",
     "REQUEST_CHAIN", "REQUIRED_KEYS", "read_jsonl", "validate",
     "FleetView",
     "DashboardServer", "build_frame", "dashboard_html", "render_frame",

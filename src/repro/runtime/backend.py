@@ -59,6 +59,7 @@ import time
 
 from ..core.scheduler import ScheduleResult
 from ..core.workload import Workload
+from ..obs.trace import NULL_TRACER
 
 
 class WorkerLost(Exception):
@@ -263,6 +264,12 @@ class AnalyticBackend(ExecutionBackend):
 # ---------------------------------------------------------------------------
 # real execution: the shard_map pipeline
 # ---------------------------------------------------------------------------
+#: device programs that indexing a device array by one integer enqueues
+#: outside a jit: a dynamic_slice then a squeeze on one device, four on
+#: an array sharded over a mesh (jax 0.9; tests/test_obs_spans.py)
+INDEX_PROGRAMS = 2
+SHARDED_INDEX_PROGRAMS = 4
+
 class PallasPipelineBackend(ExecutionBackend):
     """Runs batches through the shard_map pipeline executors in
     ``runtime.pipeline_exec``.
@@ -284,7 +291,9 @@ class PallasPipelineBackend(ExecutionBackend):
       * "auto"  — mesh when enough devices are visible, else chain
 
     ``output_platforms`` counts executed batches by the platform of the
-    device their output landed on ("tpu", "cpu", ...).
+    device their output landed on ("tpu", "cpu", ...). ``launches`` counts
+    the device programs ``dispatch`` enqueues; ``tracer`` (handed on by
+    the Engine) times the microbatch build, ``dispatch`` and the resolve.
 
     Measured stage times are real wall seconds (``measured_sim_clock`` is
     False): they are NOT comparable to the schedule's simulated-seconds
@@ -310,8 +319,24 @@ class PallasPipelineBackend(ExecutionBackend):
         # the stage structure, so cell evictions/readmissions don't pay
         # the jit cost twice
         self.prepared: dict = {}
+        self.launches = 0
+        self.tracer = NULL_TRACER
 
     # -- stage lowering ------------------------------------------------------
+    @staticmethod
+    def stage_name(kinds) -> str:
+        """Name of a chain-mode stage program, from its kernel kinds with
+        runs counted: ("spmm", "gemm") -> "stage_spmm_gemm", ("gemm",
+        "gemm") -> "stage_gemm2"."""
+        parts: list[list] = []
+        for kind in kinds:
+            if parts and parts[-1][0] == kind:
+                parts[-1][1] += 1
+            else:
+                parts.append([kind, 1])
+        return "stage_" + "_".join(k if n == 1 else f"{k}{n}"
+                                   for k, n in parts)
+
     def _stage_fn(self, kinds):
         import jax
         import jax.numpy as jnp
@@ -381,13 +406,15 @@ class PallasPipelineBackend(ExecutionBackend):
             # math to the executor's per-microbatch path, but jitted per
             # stage so the stage loop can be timed stage by stage (the
             # measured times the straggler monitors consume)
-            def stage_apply(fn):
+            def stage_apply(fn, kinds):
                 def apply(w, micro):
                     return jax.vmap(lambda x: fn({"w": w}, x))(micro)
+                apply.__name__ = self.stage_name(kinds)
                 return jax.jit(apply)
 
             mode = "chain"
-            payload = (tuple(stage_apply(f) for f in fns), params)
+            payload = (tuple(stage_apply(f, k)
+                             for f, k in zip(fns, stage_kinds)), params)
         self.prepared[cache_key] = (mode, payload)
         return PipelineHandle(schedule, workload, epoch=epoch,
                               backend=self.name, payload=payload, mode=mode)
@@ -408,15 +435,20 @@ class PallasPipelineBackend(ExecutionBackend):
         """Enqueue ``micro`` (m, act_batch, act_dim) through the handle's
         stages without blocking. Returns the device arrays to wait on, in
         completion order; the last is the pipeline output (m, B, F)."""
-        if handle.mode == "mesh":
-            return (handle.payload(micro),)
-        stage_jits, params = handle.payload
-        outs = []
-        x = micro
-        for s, sj in enumerate(stage_jits):
-            x = sj(params["w"][s], x)
-            outs.append(x)
-        return tuple(outs)
+        with self.tracer.span("backend", "backend.dispatch", 0.0):
+            if handle.mode == "mesh":
+                # the pipeline program, then the head's slice of its output
+                self.launches += 1 + SHARDED_INDEX_PROGRAMS
+                return (handle.payload(micro),)
+            stage_jits, params = handle.payload
+            outs = []
+            x = micro
+            for s, sj in enumerate(stage_jits):
+                x = sj(params["w"][s], x)
+                outs.append(x)
+            # per stage: the weight picked by index, then the stage's jit
+            self.launches += len(stage_jits) * (INDEX_PROGRAMS + 1)
+            return tuple(outs)
 
     def submit(self, handle, batch, t0: float) -> BackendFuture:
         """Dispatch the batch to the device WITHOUT blocking (jax dispatch
@@ -436,19 +468,23 @@ class PallasPipelineBackend(ExecutionBackend):
         modeled."""
         n = batch_size(batch)
         base = _analytic_report(handle.schedule, n, t0)
-        micro = self.microbatches(n)       # host-side input build: not timed
+        tracer = self.tracer
+        # host-side input build and copy: not in the measured stage times
+        with tracer.span("backend", "backend.microbatches", t0):
+            micro = self.microbatches(n)
         w0 = time.perf_counter()
         outs = self.dispatch(handle, micro)
 
         def resolve():
-            meas, prev = [], w0
-            for o in outs:                 # device runs stages in order
-                o.block_until_ready()
-                now = time.perf_counter()
-                meas.append(now - prev)
-                prev = now
-            self.output_platforms.update(
-                {d.platform for d in outs[-1].devices()})
+            with tracer.span("backend", "backend.resolve", t0):
+                meas, prev = [], w0
+                for o in outs:             # device runs stages in order
+                    o.block_until_ready()
+                    now = time.perf_counter()
+                    meas.append(now - prev)
+                    prev = now
+                self.output_platforms.update(
+                    {d.platform for d in outs[-1].devices()})
             wall = prev - w0
             if handle.mode == "mesh":
                 est = base.stage_times

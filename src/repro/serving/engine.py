@@ -162,7 +162,8 @@ class Engine:
         self.backend = backend or AnalyticBackend()
         self.max_cells = max_cells
         # span bus (repro.obs): cell admissions/evictions land on the
-        # "engine" trace; NULL (zero-cost) unless the Router wires one in
+        # "engine" trace; NULL (zero-cost) unless the Router wires one in.
+        # Setting it hands it on to the DP and the backend (see tracer)
         self.tracer = tracer or NULL_TRACER
         # when set, stages placed on a probation (re-admitted) device pool
         # get tightened straggler thresholds in new cells' monitors
@@ -178,6 +179,20 @@ class Engine:
         # cell mid-batch, its devices stay physically busy until the batch
         # drains — new admissions must not double-count that capacity
         self.busy_floor = 0.0
+
+    @property
+    def tracer(self):
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        """The Engine's tracer, passed on to the DynamicScheduler and to a
+        backend that accepts one (has a ``tracer`` attribute), so the DP
+        solve and the backend's host work are timed by the same spans."""
+        self._tracer = tracer
+        for part in (self.dyn, self.backend):
+            if hasattr(part, "tracer"):
+                part.tracer = tracer
 
     # -- capacity accounting --------------------------------------------------
     def allocated(self) -> dict:
@@ -263,6 +278,10 @@ class Engine:
         return max(t, t_free)
 
     def _admit(self, wl, key, t: float) -> tuple[Cell, float]:
+        with self.tracer.span("engine", "engine.admit", t):
+            return self._admit_cell(wl, key, t)
+
+    def _admit_cell(self, wl, key, t: float) -> tuple[Cell, float]:
         # schedule on the STABLE fair-share cap, not the instantaneous free
         # vector: the DP cache is keyed by (sig, mode, pool), and a pool
         # that churns with residual allocations would fragment it into a
@@ -281,7 +300,8 @@ class Engine:
         while len(self.cells) >= self.max_cells or not self._fits_free(need):
             t = self._evict_one(t)
         t = max(t, self.busy_floor)
-        handle = self.backend.prepare(res, wl, epoch=self.dyn.epoch)
+        with self.tracer.span("engine", "backend.prepare", t):
+            handle = self.backend.prepare(res, wl, epoch=self.dyn.epoch)
         # monitor baselines come from the handle's schedule, not the DP's:
         # a cluster backend may hand back a *host-adjusted* schedule (the
         # owning worker's physics, possibly a different stage split), and
@@ -372,19 +392,21 @@ class Engine:
         advances immediately from the future's simulated finish, so
         ``ready`` keeps a second batch off the cell until the caller reaps
         — the one-in-flight-per-cell invariant."""
-        cell, t0 = self._acquire(batch.wl, now)
-        t0 = max(t0, cell.busy_until)
-        # _acquire swept stale cells, so the handle's epoch is current here
-        future = self.backend.submit(cell.handle, batch, t0)
-        # charge the replica that will execute (cluster futures carry the
-        # routed worker id); unreplicated cells keep their single clock
-        rep = cell.advance(getattr(future, "worker", None), future.finish)
-        cell.last_used = t0
-        cell.dispatches += 1
-        self.last_cell = cell
-        inf = InFlight(self._next_seq, cell, batch, future, rep=rep)
-        self._next_seq += 1
-        self.inflight.append(inf)
+        with self.tracer.span("engine", "engine.submit", now):
+            cell, t0 = self._acquire(batch.wl, now)
+            t0 = max(t0, cell.busy_until)
+            # _acquire swept stale cells, so the handle's epoch is current
+            future = self.backend.submit(cell.handle, batch, t0)
+            # charge the replica that will execute (cluster futures carry
+            # the routed worker id); unreplicated cells keep one clock
+            rep = cell.advance(getattr(future, "worker", None),
+                               future.finish)
+            cell.last_used = t0
+            cell.dispatches += 1
+            self.last_cell = cell
+            inf = InFlight(self._next_seq, cell, batch, future, rep=rep)
+            self._next_seq += 1
+            self.inflight.append(inf)
         return inf
 
     def reap(self, upto: float | None = None) -> list:

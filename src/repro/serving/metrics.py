@@ -111,6 +111,9 @@ class ServingMetrics:
         self.steals = 0
         # wall seconds per placement decision (repro.obs self-metrics)
         self.place_s: list[float] = []
+        # wall seconds from Router.submit to a request's first dispatch,
+        # recorded only while a tracer times spans (Tracer.timing)
+        self.queue_wait_s: list[float] = []
         # (t, watts) samples recorded by the ParetoGovernor after each
         # tick's budget enforcement (simulated, deterministic)
         self.power_samples: list[tuple[float, float]] = []

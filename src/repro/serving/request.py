@@ -29,6 +29,10 @@ class Request:
     start: float = 0.0
     finish: float = 0.0
     energy: float = 0.0
+    # wall clock (perf_counter) at Router.submit, stamped only while a
+    # tracer times spans; cleared when the first dispatch records the wait
+    wall_submit: float | None = dataclasses.field(
+        default=None, compare=False, repr=False)
 
     @property
     def latency(self) -> float:

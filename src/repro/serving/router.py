@@ -128,14 +128,15 @@ class Router:
         self.estimator = estimator
         # span bus (repro.obs.Tracer): every request gets a root span on
         # trace "r<rid>"; router housekeeping (placement, mode flips,
-        # demotions) lands on the "router" trace. Spans are derived
+        # demotions) lands on the "router" trace, and the cycle's host
+        # work is timed by duration spans on it. Spans are derived
         # outputs only — nothing below reads tracer state back — so
         # tracing never perturbs scheduling decisions or replay.
         self.tracer = tracer or NULL_TRACER
         self.engine = engine or Engine(dyn, backend, max_cells=max_cells,
                                        probation=probation,
                                        tracer=self.tracer)
-        if self.tracer.enabled and not self.engine.tracer.enabled:
+        if self.tracer.timing and not self.engine.tracer.timing:
             self.engine.tracer = self.tracer   # caller-supplied engine
         # steals reported by the cluster controller during the engine
         # submit underway (on_steal fires inside ExecutionBackend.submit);
@@ -177,32 +178,37 @@ class Router:
         """Admit one request at simulated time ``now`` (seconds). Returns
         False (and counts a drop) when the queue is full or the deadline
         cannot survive the Engine's signature-aware wait estimate."""
-        self.policy.observe_arrival(now, wl=req.wl)
-        if self.tenancy is not None and req.tenant:
-            req.priority = self.tenancy.priority(req.tenant)
-        est = self.engine.est_wait(now, req.wl)
         tr = self.tracer
-        if tr.enabled:
-            tr.open_root(f"r{req.rid}", "request", req.arrival)
-        ok = self.queue.admit(req, now, est_wait=est)
-        if not ok:
-            self.metrics.record_drop(tenant=req.tenant)
+        if tr.timing:
+            req.wall_submit = _time.perf_counter()   # for queue_wait_s
+        with tr.span("router", "router.submit", now):
+            self.policy.observe_arrival(now, wl=req.wl)
+            if self.tenancy is not None and req.tenant:
+                req.priority = self.tenancy.priority(req.tenant)
+            est = self.engine.est_wait(now, req.wl)
             if tr.enabled:
-                tr.instant(f"r{req.rid}", "reject", now,
+                tr.open_root(f"r{req.rid}", "request", req.arrival)
+            ok = self.queue.admit(req, now, est_wait=est)
+            if not ok:
+                self.metrics.record_drop(tenant=req.tenant)
+                if tr.enabled:
+                    tr.instant(f"r{req.rid}", "reject", now,
+                               est_wait=round(est, 9))
+                    tr.close_root(f"r{req.rid}", now, status="rejected")
+            elif tr.enabled:
+                tr.instant(f"r{req.rid}", "admit", now, kind=req.kind,
                            est_wait=round(est, 9))
-                tr.close_root(f"r{req.rid}", now, status="rejected")
-        elif tr.enabled:
-            tr.instant(f"r{req.rid}", "admit", now, kind=req.kind,
-                       est_wait=round(est, 9))
-        # priority admission may have evicted lower-class queued requests
-        # to make room: account them as drops (they were counted admitted)
-        for victim in self.queue.take_displaced():
-            self.batcher.forget([victim])
-            self.metrics.record_drop(tenant=victim.tenant)
-            if tr.enabled:
-                tr.instant(f"r{victim.rid}", "displace", now,
-                           by=req.tenant or req.rid)
-                tr.close_root(f"r{victim.rid}", now, status="displaced")
+            # priority admission may have evicted lower-class queued
+            # requests to make room: account them as drops (they were
+            # counted admitted)
+            for victim in self.queue.take_displaced():
+                self.batcher.forget([victim])
+                self.metrics.record_drop(tenant=victim.tenant)
+                if tr.enabled:
+                    tr.instant(f"r{victim.rid}", "displace", now,
+                               by=req.tenant or req.rid)
+                    tr.close_root(f"r{victim.rid}", now,
+                                  status="displaced")
         return ok
 
     # -- elastic events (runtime/elastic.py semantics) ------------------------
@@ -357,8 +363,28 @@ class Router:
         with the rest of the loop); batches finishing beyond ``now`` stay
         in flight for a later cycle (or ``drain``)."""
         self._now = now
-        self._run_hooks(now)
-        done: list[Request] = list(self._reap(upto=now, at=now))
+        tr = self.tracer
+        with tr.span("router", "router.step", now):
+            self._run_hooks(now)
+            with tr.span("router", "router.reap", now):
+                done: list[Request] = list(self._reap(upto=now, at=now))
+            with tr.span("router", "router.policy", now):
+                self._expire_and_flip(now)
+            self._preempt_pass(now)
+            while True:
+                with tr.span("router", "batcher.next_batch", now):
+                    batch = self.batcher.next_batch(self.queue, now,
+                                                    ready=self._ready(now))
+                if batch is None:
+                    break
+                with tr.span("router", "router.dispatch", now):
+                    done.extend(self._dispatch(batch, now))
+        return done
+
+    def _expire_and_flip(self, now: float) -> None:
+        """Drop queued requests whose deadline passed, then let the
+        load-watermark policy flip the objective (unless a governor owns
+        it)."""
         dead = self.queue.expire(now)
         if dead:
             for req in dead:
@@ -378,14 +404,6 @@ class Router:
                 self.dyn.set_mode(mode)                 # epoch bump
                 if self.tracer.enabled:
                     self.tracer.instant("router", "mode", now, mode=mode)
-        self._preempt_pass(now)
-        while True:
-            batch = self.batcher.next_batch(self.queue, now,
-                                            ready=self._ready(now))
-            if batch is None:
-                break
-            done.extend(self._dispatch(batch, now))
-        return done
 
     # -- tenancy preemption ---------------------------------------------------
     def _preempt_pass(self, now: float) -> None:
@@ -402,8 +420,13 @@ class Router:
         pressure = getattr(self.batcher, "blocked_pressure", None)
         if pressure is None:
             return
+        with self.tracer.span("router", "router.preempt_pass", now):
+            self._preempt_rounds(pressure, now)
+
+    def _preempt_rounds(self, pressure, now: float) -> None:
+        """``_preempt_pass``'s eviction rounds: each evicts at most one
+        batch, bounded by the in-flight set."""
         ready = self._ready(now)
-        # each round evicts at most one batch; bounded by the in-flight set
         for _ in range(len(self.engine.inflight)):
             blocked = pressure(self.queue, now, ready)
             if blocked is None:
@@ -477,27 +500,27 @@ class Router:
         with its worker (report None) re-queues exactly like the async
         path."""
         solves0 = self.dyn.dp_solves
+        tr = self.tracer
         w0 = _time.perf_counter()
         inf = self.engine.submit(batch, t0)
         wall = _time.perf_counter() - w0
+        if tr.timing:
+            self._record_queue_wait(batch, w0)
         # placement-decision latency (DP lookup/solve + cell acquire +
         # backend dispatch) — the scheduler self-metric HTS warns becomes
         # the bottleneck at scale
         self.metrics.record_placement(wall)
         bid = len(self.dispatches)
         self._record_dispatch(inf.cell, batch, inf.t0, inf.finish)
-        tr = self.tracer
         if tr.enabled:
-            cache_hit = self.dyn.dp_solves == solves0
-            wall_ms = round(wall * 1e3, 6)
             tr.instant("router", "place", inf.t0, bid=bid,
                        cell=inf.cell.cid, n=len(batch),
-                       wall_ms=wall_ms, cache_hit=cache_hit)
+                       wall_ms=round(wall * 1e3, 6),
+                       cache_hit=self.dyn.dp_solves == solves0)
             for req in batch.requests:
                 trc = f"r{req.rid}"
                 tr.child(trc, "batch", req.arrival, inf.t0, bid=bid)
-                tr.instant(trc, "solve", inf.t0,
-                           cache_hit=cache_hit, wall_ms=wall_ms)
+                tr.instant(trc, "solve", inf.t0)
                 tr.instant(trc, "submit", inf.t0, cell=inf.cell.cid,
                            bid=bid, finish=round(inf.finish, 9))
             for frm, to, _n in self._pending_steals:
@@ -509,6 +532,16 @@ class Router:
             return []
         cell, report = self.engine.resolve(inf)
         return self._apply_report(cell, batch, report, at=inf.t0)
+
+    def _record_queue_wait(self, batch: Batch, w: float) -> None:
+        """Wall seconds from ``submit`` to this dispatch, once a request
+        (its first dispatch), into ``ServingMetrics.queue_wait_s``; only
+        requests stamped by a timing tracer count."""
+        waits = self.metrics.queue_wait_s
+        for req in batch.requests:
+            if req.wall_submit is not None:
+                waits.append(w - req.wall_submit)
+                req.wall_submit = None
 
     def _record_dispatch(self, cell, batch: Batch, t0: float,
                          finish: float) -> None:
