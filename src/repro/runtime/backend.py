@@ -264,10 +264,8 @@ class AnalyticBackend(ExecutionBackend):
 # ---------------------------------------------------------------------------
 # real execution: the shard_map pipeline
 # ---------------------------------------------------------------------------
-#: device programs that indexing a device array by one integer enqueues
-#: outside a jit: a dynamic_slice then a squeeze on one device, four on
-#: an array sharded over a mesh (jax 0.9; tests/test_obs_spans.py)
-INDEX_PROGRAMS = 2
+#: device programs that indexing an array sharded over a mesh by one
+#: integer enqueues outside a jit (jax 0.9; tests/test_obs_spans.py)
 SHARDED_INDEX_PROGRAMS = 4
 
 class PallasPipelineBackend(ExecutionBackend):
@@ -287,7 +285,8 @@ class PallasPipelineBackend(ExecutionBackend):
       * "mesh"  — a (sum of DP stage counts,) jax mesh; raises when fewer
                   devices are visible
       * "chain" — the same stage chain as per-stage jits, sequential on
-                  the default device
+                  the default device, each stage's weight sliced once at
+                  ``prepare``: one device program a stage
       * "auto"  — mesh when enough devices are visible, else chain
 
     ``output_platforms`` counts executed batches by the platform of the
@@ -355,9 +354,20 @@ class PallasPipelineBackend(ExecutionBackend):
             return x
         return fn
 
+    def stage_weights(self, n_stages: int):
+        """The stacked (n_stages, act_dim, act_dim) f32 stage weights: a
+        scaled identity + deterministic off-diagonal per stage, so stage
+        order matters (parity/permutations are observable)."""
+        import jax.numpy as jnp
+
+        eye = jnp.eye(self.act_dim, dtype=jnp.float32)
+        return jnp.stack([
+            (0.8 + 0.02 * s) * eye
+            + 0.01 * jnp.roll(eye, s + 1, axis=1)
+            for s in range(n_stages)])
+
     def prepare(self, schedule, workload, *, epoch: int = 0) -> PipelineHandle:
         import jax
-        import jax.numpy as jnp
         import numpy as np
 
         stages = schedule.pipeline.stages
@@ -375,13 +385,7 @@ class PallasPipelineBackend(ExecutionBackend):
                                   backend=self.name, payload=payload,
                                   mode=mode)
         fns = [self._stage_fn(kinds) for kinds in stage_kinds]
-        # per-stage weight: scaled identity + deterministic off-diagonal so
-        # stage order matters (parity/permutations are observable)
-        eye = jnp.eye(F, dtype=jnp.float32)
-        ws = jnp.stack([
-            (0.8 + 0.02 * s) * eye
-            + 0.01 * jnp.roll(eye, s + 1, axis=1)
-            for s in range(n_stages)])
+        ws = self.stage_weights(n_stages)
         params = {"w": ws}
 
         n_dev = sum(group_sizes)
@@ -413,8 +417,12 @@ class PallasPipelineBackend(ExecutionBackend):
                 return jax.jit(apply)
 
             mode = "chain"
+            # one (F, F) weight a stage, sliced here once: indexing the
+            # stacked array in dispatch would enqueue two programs a stage
+            # on every batch
             payload = (tuple(stage_apply(f, k)
-                             for f, k in zip(fns, stage_kinds)), params)
+                             for f, k in zip(fns, stage_kinds)),
+                       {"w": tuple(ws)})
         self.prepared[cache_key] = (mode, payload)
         return PipelineHandle(schedule, workload, epoch=epoch,
                               backend=self.name, payload=payload, mode=mode)
@@ -443,11 +451,11 @@ class PallasPipelineBackend(ExecutionBackend):
             stage_jits, params = handle.payload
             outs = []
             x = micro
-            for s, sj in enumerate(stage_jits):
-                x = sj(params["w"][s], x)
+            for sj, w in zip(stage_jits, params["w"]):
+                x = sj(w, x)
                 outs.append(x)
-            # per stage: the weight picked by index, then the stage's jit
-            self.launches += len(stage_jits) * (INDEX_PROGRAMS + 1)
+            # one program a stage: its jit, on the weight sliced at prepare
+            self.launches += len(stage_jits)
             return tuple(outs)
 
     def submit(self, handle, batch, t0: float) -> BackendFuture:

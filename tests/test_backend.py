@@ -107,6 +107,36 @@ def test_pallas_backend_actually_executes():
     assert h2.payload is h.payload
 
 
+def test_chain_weights_sliced_at_prepare_give_the_same_outputs():
+    """Chain mode keeps one weight a stage, sliced once at prepare: each
+    is the stacked weight's row ``s`` bit for bit, in stage order, and
+    ``dispatch`` computes what picking ``stacked[s]`` per stage does."""
+    import numpy as np
+
+    res = fresh_dyn().submit(WL_A)
+    n = len(res.pipeline.stages)
+    assert n == 2
+    be = PallasPipelineBackend(mode="chain", act_dim=4, act_batch=2)
+    h = be.prepare(res, WL_A)
+    jits, params = h.payload
+    stacked = be.stage_weights(n)
+    assert len(params["w"]) == n
+    for s, w in enumerate(params["w"]):
+        assert w.shape == (4, 4)
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(stacked[s]))
+    micro = be.microbatches(3)
+    want, x = [], micro
+    for s, sj in enumerate(jits):
+        x = sj(stacked[s], x)
+        want.append(x)
+    n0 = be.launches
+    got = be.dispatch(h, micro)
+    assert be.launches - n0 == n
+    assert len(got) == n
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_pallas_auto_mode_reports_chain_and_mesh_mode_refuses():
     """On one device ``auto`` records the sequential chain on the handle;
     ``mesh`` with too few devices raises instead of degrading."""

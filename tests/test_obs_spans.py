@@ -267,6 +267,8 @@ def test_launch_counter_matches_executed_programs(mode, tmp_path):
     got_mode, stages, launches, executed = p.stdout.split()
     assert got_mode == mode and int(stages) > 1
     assert int(launches) == int(executed) > 0
+    if mode == "chain":        # three dispatches, one program a stage
+        assert int(launches) == 3 * int(stages)
 
 
 def test_chain_stage_programs_are_named_by_their_kinds():
@@ -371,7 +373,7 @@ def test_profiled_stack_notes_the_window(monkeypatch):
     out = program_spans.run("paper-mix-diurnal", 2**31 + 11, 0.3, True,
                             log=lambda msg: None)
     assert out["correct"] is True and out["failed"] == 0
-    assert out["batches"] > 0 and out["launches"] >= 3 * out["batches"]
+    assert out["batches"] > 0 and out["launches"] >= out["batches"]
     assert {"batch_form_ms", "micro_build_ms", "launch_us",
             "launches_per_batch", "queue_wait_wall_p95_ms"} <= \
         set(out["program"])
