@@ -1,13 +1,13 @@
 """The one arrival generator: reads a traffic file and yields, tick by tick,
 the requests that arrive in each tick, from ``--seed`` alone.
 
-A traffic file (``bench/traffic/<mix>.json``) holds only parameters:
+A traffic file (``traffic/<mix>.json``, found by ``files.find``) holds
+only parameters:
 
 ``tick_s``
     length of one control tick in simulated seconds.
 ``arrivals``
-    Poisson(rate(t) * tick) arrivals per tick, placed uniformly inside it,
-    with ``rate(t) = base * swing(t) * burst(t)``:
+    ``rate(t) = base * swing(t) * burst(t)``, with one of:
 
     ``mean_rate``
         requests per simulated second, averaged over swings and bursts.
@@ -15,11 +15,15 @@ A traffic file (``bench/traffic/<mix>.json``) holds only parameters:
         a cosine load curve, 1 at t = 0 and ``trough_share`` at half a
         period (the arithmetic of ``repro.serving.traffic.TrafficSim.rate``,
         copied so that no change to the program moves the yardstick).
-    ``bursts``: ``{"mult", "len_s", "period_s"}`` or null
-        one burst of ``len_s`` at ``mult`` times the rate in every
-        ``period_s``, starting at a point of the period that the seed draws.
-        Every period holds the same burst, so every seed offers the same
-        load; only where the bursts fall changes.
+        Poisson(rate(t) * tick) arrivals per tick, placed uniformly inside.
+    ``bursts``: ``{"mult", "len_s", "period_s", "start_s"}`` or null
+        one burst of ``len_s`` at ``mult`` times the rate, ``start_s``
+        into every ``period_s``. Each stretch of constant rate (a burst, or
+        the base between two) holds its expected number of arrivals,
+        rounded, placed uniformly inside it (a Poisson process given its
+        count), with the mix and the tenants in exact shares of that number
+        (largest remainders), shuffled. Every seed offers the same work in
+        each stretch, in another order and at other times. Not with a swing.
 
     ``base`` is ``mean_rate`` over the mean of ``swing * burst``.
 ``requests``
@@ -36,14 +40,14 @@ window lasts.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import json
 import math
-from pathlib import Path
 
 import numpy as np
 
-BENCH = Path(__file__).resolve().parent
+from . import files
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,12 +60,22 @@ class Arrival:
 
 
 def load_traffic(name: str) -> dict:
-    return json.loads((BENCH / "traffic" / f"{name}.json").read_text())
+    return json.loads(files.find("traffic", name, ".json").read_text())
 
 
 def _cum(weights) -> np.ndarray:
     w = np.asarray(weights, dtype=float)
     return np.cumsum(w / w.sum())
+
+
+def _apportion(cum: np.ndarray, n: int) -> np.ndarray:
+    """``n`` items split over the shares that ``cum`` accumulates, by
+    largest remainders: the count of each, summing to ``n``."""
+    exact = np.diff(cum, prepend=0.0) * n
+    counts = np.floor(exact).astype(int)
+    rest = n - int(counts.sum())
+    counts[np.argsort(-(exact - counts), kind="stable")[:rest]] += 1
+    return counts
 
 
 class Stream:
@@ -70,12 +84,14 @@ class Stream:
     def __init__(self, spec: dict, seed: int):
         self.tick = float(spec["tick_s"])
         self.slack = spec.get("deadline_slack_s")
-        bursts_ss, arrivals_ss = np.random.SeedSequence(seed).spawn(2)
-        self.rng = np.random.default_rng(arrivals_ss)
-        self.burst_rng = np.random.default_rng(bursts_ss)
+        # the second child of the seed, as every stream measured so far
+        self.rng = np.random.default_rng(
+            np.random.SeedSequence(seed).spawn(2)[1])
         arr = spec["arrivals"]
         self.swing = arr.get("swing")
         self.bursts = arr.get("bursts")
+        if self.swing and self.bursts:
+            raise ValueError("a traffic mix has a swing or bursts, not both")
         mean = 1.0
         if self.swing:
             mean *= 0.5 * (1.0 + float(self.swing["trough_share"]))
@@ -83,8 +99,8 @@ class Stream:
             b = self.bursts
             mean *= 1.0 + (b["mult"] - 1.0) * b["len_s"] / b["period_s"]
         self.base = float(arr["mean_rate"]) / mean
-        self._burst_period = -1
-        self._burst_start = 0.0
+        self._pending: collections.deque = collections.deque()  # drawn
+        self._drawn_periods = 0
         req = spec["requests"]
         self.items = [(m["name"], m["kind"]) for m in req["mix"]]
         self.cum = _cum([m["weight"] for m in req["mix"]])
@@ -96,15 +112,8 @@ class Stream:
 
     def _burst_mult(self, t: float) -> float:
         b = self.bursts
-        period = float(b["period_s"])
-        k = int(t // period)
-        while self._burst_period < k:      # one draw per period, in order
-            self._burst_period += 1
-            self._burst_start = self.burst_rng.uniform(
-                0.0, period - float(b["len_s"]))
-        off = t - k * period
-        inside = self._burst_start <= off < self._burst_start + b["len_s"]
-        return float(b["mult"]) if inside else 1.0
+        off = t % float(b["period_s"]) - float(b["start_s"])
+        return float(b["mult"]) if 0.0 <= off < b["len_s"] else 1.0
 
     def rate(self, t: float) -> float:
         """Offered requests per simulated second at time ``t``."""
@@ -118,10 +127,47 @@ class Stream:
             r *= self._burst_mult(t)
         return r
 
+    def _arrival(self, at: float, pick: int, tenant: str) -> Arrival:
+        ddl = None if self.slack is None else at + float(self.slack)
+        name, kind = self.items[pick]
+        return Arrival(at, name, kind, tenant, ddl)
+
+    def _draw_period(self) -> list[Arrival]:
+        """The arrivals of the next burst period."""
+        b = self.bursts
+        period = float(b["period_s"])
+        t0 = self._drawn_periods * period
+        self._drawn_periods += 1
+        start = t0 + float(b["start_s"])
+        end = start + float(b["len_s"])
+        out = []
+        for lo, hi, mult in ((t0, start, 1.0), (start, end, b["mult"]),
+                             (end, t0 + period, 1.0)):
+            n = int(round(self.base * float(mult) * (hi - lo)))
+            times = np.sort(self.rng.uniform(lo, hi, n))
+            picks = self.rng.permutation(
+                np.repeat(np.arange(len(self.items)),
+                          _apportion(self.cum, n)))
+            tenants = [""] * n if self.tenant_cum is None else [
+                self.tenants[i] for i in self.rng.permutation(np.repeat(
+                    np.arange(len(self.tenants)),
+                    _apportion(self.tenant_cum, n)))]
+            out.extend(self._arrival(float(at), int(p), ten)
+                       for at, p, ten in zip(times, picks, tenants))
+        return out
+
     def next_tick(self) -> list[Arrival]:
         """Arrivals in [t, t + tick); advances the stream by one tick."""
         t = self.t
         self.t = t + self.tick
+        if self.bursts:
+            pending = self._pending
+            while not pending or pending[-1].t < self.t:
+                pending.extend(self._draw_period())
+            out = []
+            while pending[0].t < self.t:
+                out.append(pending.popleft())
+            return out
         rng = self.rng
         n = int(rng.poisson(self.rate(t) * self.tick))
         if not n:
@@ -134,10 +180,5 @@ class Stream:
             idx = np.searchsorted(self.tenant_cum, rng.random(n),
                                   side="right")
             tenants = [self.tenants[i] for i in idx]
-        out = []
-        for o, p, ten in zip(offs, picks, tenants):
-            at = t + float(o)
-            ddl = None if self.slack is None else at + float(self.slack)
-            name, kind = self.items[int(p)]
-            out.append(Arrival(at, name, kind, ten, ddl))
-        return out
+        return [self._arrival(t + float(o), int(p), ten)
+                for o, p, ten in zip(offs, picks, tenants)]

@@ -2,12 +2,13 @@
 
 Serving layers (``serving.router``, ``serving.engine``, ``tenancy``): after
 the drain, every request the harness submitted has completed once or been
-counted as dropped, and none is queued or in flight.
+counted as dropped, and none is queued or in flight; every batch
+dispatched in the window was recorded, and what was kept of its output is
+on the accelerator.
 
-``runtime.backend`` on the chip: the output of every batch dispatched in the
-window, kept on the device until the window closed, is on the accelerator
-and matches the plain reference of its stage chain (``reference.py``) at the
-precision the configuration states.
+The device work: the configuration's plug-in (``plugins/<name>.py``)
+compares what was kept of each batch with its plain reference and gives
+its own numbers and ``LIMITS``.
 
 Each number is compared with its limit; ``correct`` holds when every number
 is at or under its limit. The readings each limit was set from are in
@@ -17,10 +18,6 @@ from __future__ import annotations
 
 import collections
 
-import numpy as np
-
-from . import reference
-
 # number -> limit
 LIMITS = {
     "lost": 0,             # requests neither completed nor dropped
@@ -28,7 +25,6 @@ LIMITS = {
     "duplicated": 0,       # completions beyond one per request
     "off_device": 0,       # checked outputs not on the accelerator
     "unchecked": 0,        # batches dispatched in the window with no output
-    "worst_answer_gap": 3.0e-4,
 }
 
 
@@ -39,57 +35,30 @@ def accounting(submitted: int, completed_rids, dropped: int, left: int):
             "duplicated": len(completed_rids) - uniq}
 
 
-def output_gaps(records, *, operands: str, act_batch: int = 8,
-                act_dim: int = 16, control: bool = False) -> dict:
-    """``records``: (stage kinds, microbatch count, host array) per batch.
-    A batch of n requests carries min(n, 8) microbatches, one answer each;
-    an answer's gap is the mean absolute difference from the reference over
-    its (8, 16) values, and ``worst_answer_gap`` is the largest over every
-    answer of every batch. With ``control`` the bfloat16 chain stands in
-    the program's place."""
-    refs, ctrl = {}, {}
-    worst = max_abs = 0.0
-    for kinds, m, got in records:
-        key = (kinds, m)
-        if key not in refs:
-            micro = reference.microbatch(m, act_batch, act_dim)
-            refs[key] = reference.stage_chain(kinds, micro,
-                                              operands=operands)
-            if control:
-                ctrl[key] = reference.control_chain(kinds, micro)
-        out = ctrl[key] if control else np.asarray(got, np.float32)
-        if out.shape != refs[key].shape or not np.isfinite(out).all():
-            return {"worst_answer_gap": float("inf"),
-                    "max_abs_gap": float("inf")}
-        d = np.abs(out - refs[key])
-        worst = max(worst, float(d.mean(axis=(1, 2)).max()))
-        max_abs = max(max_abs, float(d.max()))
-    return {"worst_answer_gap": worst, "max_abs_gap": max_abs}
+def recorded(records, platform: str, dispatched: int) -> dict:
+    """``off_device`` and ``unchecked`` of the window's records
+    (``stack.Record``), and ``_by_shape``, the count of records by (stages,
+    inputs), printed only."""
+    def platforms(arr):
+        devices = getattr(arr, "devices", None)
+        return {d.platform for d in devices()} if devices else set()
+
+    return {"off_device": sum(1 for r in records
+                              if platforms(r.kept) != {platform}),
+            "unchecked": dispatched - len(records),
+            "_by_shape": dict(collections.Counter((len(r.kinds), r.m)
+                                                  for r in records))}
 
 
-def outputs(records, platform: str, dispatched: int, *, operands: str,
-            act_batch: int = 8, act_dim: int = 16) -> dict:
-    import jax
-
-    off = sum(1 for _, _, arr in records
-              if {d.platform for d in arr.devices()} != {platform})
-    host = jax.device_get([arr for _, _, arr in records])
-    gaps = output_gaps([(k, m, h) for (k, m, _), h in zip(records, host)],
-                       operands=operands, act_batch=act_batch,
-                       act_dim=act_dim)
-    return {"off_device": off, "unchecked": dispatched - len(records),
-            "worst_answer_gap": gaps["worst_answer_gap"],
-            "_max_abs_gap": gaps["max_abs_gap"],
-            "_by_shape": dict(collections.Counter((len(k), m)
-                                                  for k, m, _ in records))}
-
-
-def verdict(numbers: dict) -> tuple[bool, dict]:
+def verdict(numbers: dict, limits: dict) -> tuple[bool, dict]:
     """(correct, {name: {"value", "limit"}}) over the numbers that have a
-    limit; names starting with ``_`` are printed but not compared."""
-    checks = {k: {"value": v, "limit": LIMITS[k]}
-              for k, v in numbers.items() if k in LIMITS}
-    missing = set(LIMITS) - set(checks)
+    limit in ``LIMITS`` or in the plug-in's ``limits``, every one of which
+    has to be there; names starting with ``_`` are printed but not
+    compared."""
+    limits = {**LIMITS, **limits}
+    checks = {k: {"value": v, "limit": limits[k]}
+              for k, v in numbers.items() if k in limits}
+    missing = set(limits) - set(checks)
     ok = not missing and all(c["value"] <= c["limit"]
                              for c in checks.values())
     return ok, checks

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Readings that the limits of ``check.py`` are set from, in one process.
+"""Readings that the proxy plug-in's limit is set from, in one process.
 
     python3 bench/control.py --workload <cell> --seeds 12 --control-seeds 3 \\
         --seconds 10 --first-seed <n>
@@ -9,9 +9,10 @@ at the cell's own load, exactly as a run makes them; then the program's
 numbers after the drain. For the first ``--control-seeds`` seeds also the
 control's: the reference chain in bfloat16 on the device, the nearest
 precision below the configuration's, put in the program's place on the same
-batches. One JSON line per seed, then the summary: the lower reading (the
-largest the program gives) and the upper (the smallest the control gives)
-of each gap. Benchmark runs never run the control. Exits with 2 unless JAX's
+batches (a hook of the proxy plug-in, ``plugins/proxy.py``, which both
+cells use). One JSON line per seed, then the summary: the lower reading
+(the largest the program gives) and the upper (the smallest the control
+gives) of each gap. Benchmark runs never run the control. Exits with 2 unless JAX's
 first device is a TPU.
 """
 from __future__ import annotations
@@ -29,7 +30,7 @@ def readings(workload: str, seed: int, seconds: float, *,
              control: bool) -> dict:
     import jax
 
-    from bench import check, harness
+    from bench import harness
 
     platform = jax.devices()[0].platform
     served = harness.serve_window(workload, seed, seconds,
@@ -38,17 +39,17 @@ def readings(workload: str, seed: int, seconds: float, *,
     nums = harness.numbers(served, platform)
     nums.pop("_by_shape")
     out.update(nums)
-    b = served.cfg["backend"]
-    outputs = served.backend.outputs
-    host = jax.device_get([a for _, _, a in outputs])
-    recs = [(k, m, h) for (k, m, _), h in zip(outputs, host)]
-    gaps = dict(act_batch=b["act_batch"], act_dim=b["act_dim"])
-    operands = "bfloat16" if platform == "tpu" else "float32"
-    out["vs_float32"] = check.output_gaps(recs, operands="float32", **gaps)
+    proxy = served.recorder.plugin   # the control is the proxy's hook
+    records = served.recorder.records
+    recs = proxy.host_records(records)
+    gaps = {}
+    if records:
+        _, gaps["act_batch"], gaps["act_dim"] = records[0].input.shape
+    out["vs_float32"] = proxy.output_gaps(recs, operands="float32", **gaps)
     if control:
-        out["control"] = check.output_gaps(recs, operands=operands,
-                                           control=True, **gaps)
-        out["control_vs_float32"] = check.output_gaps(
+        out["control"] = proxy.output_gaps(
+            recs, operands=proxy.operands(platform), control=True, **gaps)
+        out["control_vs_float32"] = proxy.output_gaps(
             recs, operands="float32", control=True, **gaps)
     return out
 

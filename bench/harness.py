@@ -2,9 +2,10 @@
 the check, and the result line.
 
 Set-up (timed as ``setup_s``, from process start) turns on the persistent
-compile cache, builds the stack from the cell's configuration, and serves
-the cell's own seeded stream for ``warmup_sim_s`` simulated seconds, which
-prepares and warms every stage structure that traffic produces.
+compile cache, builds the stack from the cell's configuration and its
+plug-in (``stack.py``), and serves the cell's own seeded stream for
+``warmup_sim_s`` simulated seconds, which prepares and warms every stage
+structure that traffic produces.
 
 The window continues the same stream, tick by tick on the simulated clock,
 through ``Router.submit`` and ``Router.step``, as fast as the stack runs,
@@ -12,25 +13,28 @@ for ``--seconds`` of wall time. A request's wall latency runs from the
 harness's submit to the return of the ``step`` that completed it. Modelled
 waits cost no wall time, so this is the delay that the real stack adds; a
 generator on a simulated clock cannot run late. After the window the stack
-is drained (not timed) and checked (``check.py``).
+is drained (not timed) and checked (``check.py`` and the plug-in).
+
+A traced run (``--trace 1``) serves with ``repro.obs.Tracer(profile=True)``
+in the stack, under the profiler, and hands its readers a ``Window`` that
+also carries the program's spans and counters, the device time of every
+program and what was dispatched; an untraced run builds no tracer.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import importlib.util
 import json
 import os
 import shutil
 import sys
 import tempfile
 import time
-from pathlib import Path
 
-from . import arrivals, check, stack, xtrace
+from . import arrivals, check, files, program_spans, stack, xtrace
 
-BENCH = Path(__file__).resolve().parent
-ROOT = BENCH.parent
+BENCH = files.BENCH
+ROOT = files.ROOT
 
 
 def process_start_wall() -> float:
@@ -70,7 +74,9 @@ class CompileCount:
 
 @dataclasses.dataclass
 class Window:
-    """What the metric readers read (``bench/metrics/<name>.py``)."""
+    """What the metric readers read (``metrics/<name>.py``). The fields
+    after ``reschedules`` are filled in traced runs only, and are None in
+    the others."""
     seconds: float                 # wall length of the window
     setup_s: float
     completed: int                 # requests completed in the window
@@ -78,8 +84,24 @@ class Window:
     batches: int                   # batches dispatched in the window
     place_s: list                  # placement walls of those batches
     reschedules: int               # DynamicScheduler events in the window
-    busy_s: float | None = None    # traced runs: device busy time
-    window_s: float | None = None  # traced runs: traced window length
+    busy_s: float | None = None    # device busy seconds in the trace
+    window_s: float | None = None  # traced window length, seconds
+    # program span name -> (seconds, count, self seconds) in the traced
+    # window (program_spans.py)
+    span_totals: dict | None = None
+    # backend counter -> its change over the window (stack.Recorder)
+    counters: dict | None = None
+    # wall seconds from Router.submit to the first dispatch, per request
+    # first dispatched in the window
+    queue_wait_s: list | None = None
+    # device program -> (device seconds, calls) in the traced window
+    device_programs: dict | None = None
+    device_kind: str | None = None     # as JAX names it; peaks.py's key
+    # (workload, stage kinds, inputs) of each batch dispatched in the window
+    dispatched: list | None = None
+    # the plug-in's work(workload, stage kinds, m) -> (flops, bytes), or
+    # None where it has none
+    work: object = None
 
 
 class Driver:
@@ -169,16 +191,11 @@ def _annotate_layers(router, backend, span) -> None:
 
 
 def load_benchmark() -> dict:
-    return json.loads((ROOT / "BENCHMARK.json").read_text())
+    return json.loads(files.BENCHMARK.read_text())
 
 
 def _reader(name: str):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}",
-        BENCH / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return files.load("metrics", name).read
 
 
 def metrics_for(bench: dict, cell: str, trace: bool, w: Window) -> dict:
@@ -200,7 +217,7 @@ class Served:
     """A cell's stack after its measured window and the drain."""
     cfg: dict
     router: object
-    backend: object
+    recorder: stack.Recorder
     driver: Driver
     window: Window
     attempted: int                 # requests submitted in the window
@@ -209,36 +226,43 @@ class Served:
 
 
 def serve_window(workload: str, seed: int, seconds: float, *,
-                 trace: bool = False, t_proc: float | None = None,
-                 log=print) -> Served:
+                 trace: bool = False, profile: bool | None = None,
+                 t_proc: float | None = None, log=print) -> Served:
     """Set-up, the measured window and the drain of one run of cell
     ``workload``: builds the stack, serves the warm-up, serves the window
     for ``seconds`` of wall (under the profiler with ``trace``), drains.
+    ``profile`` (default ``trace``) puts ``Tracer(profile=True)`` in the
+    stack and notes the window's counters, queue waits and dispatches.
     ``setup_s`` runs from ``t_proc`` (``time.time``), default now."""
     import jax
 
+    from repro.obs import Tracer
+
     t_proc = time.time() if t_proc is None else t_proc
+    profile = trace if profile is None else profile
     cell = next(c for c in load_benchmark()["workloads"]
                 if c["name"] == workload)
     compiles = CompileCount.get()
     cfg = stack.load_config(cell["config"])
     spec = arrivals.load_traffic(cell["traffic"])
     t_begin = time.time() - t_proc
-    router, backend = stack.build(cfg, spec["provisioned_rate"])
+    router, rec = stack.build(cfg, spec["provisioned_rate"],
+                              tracer=Tracer(profile=True) if profile
+                              else None)
     t_built = time.time() - t_proc
     stream = arrivals.Stream(spec, seed)
     driver = Driver(router, stream, cfg)
     driver.serve_until(spec["warmup_sim_s"])
     log(f"set-up: devices and imports by {t_begin:.3f} s, stack built at "
         f"{t_built:.3f} s, warm-up served by {time.time() - t_proc:.3f} s; "
-        f"{len(backend.prepared)} stage structures prepared, "
+        f"{rec.structures} stage structures prepared, "
         f"{compiles.n} programs built")
 
     trace_dir = None
     if trace:
         trace_dir = tempfile.mkdtemp(prefix="bench-trace-")
         driver.annotate = True
-        _annotate_layers(router, backend, driver._span)
+        _annotate_layers(router, rec.backend, driver._span)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0   # the harness's spans, not every call
         opts.host_tracer_level = 1
@@ -247,9 +271,11 @@ def serve_window(workload: str, seed: int, seconds: float, *,
     n_place0 = len(router.metrics.place_s)
     n_ev0 = len(router.dyn.events)
     n_pre0 = router.metrics.preemptions
+    n_wait0 = len(router.metrics.queue_wait_s)
+    counts0 = rec.counts()
     n_comp0 = compiles.n
     rid0 = driver.rid
-    backend.record = True
+    rec.record = True
     w0 = time.perf_counter()
     setup_s = time.time() - t_proc
     end = w0 + seconds
@@ -258,12 +284,18 @@ def serve_window(workload: str, seed: int, seconds: float, *,
     while w < end:
         got, w = driver.tick()
         lat.extend(got)
-    backend.record = False
+    rec.record = False
     window = Window(
         seconds=w - w0, setup_s=setup_s, completed=len(lat),
         latencies_s=lat, batches=len(router.dispatches) - n_disp0,
         place_s=router.metrics.place_s[n_place0:],
         reschedules=len(router.dyn.events) - n_ev0)
+    if profile:
+        window.counters = {k: v - counts0[k]
+                           for k, v in rec.counts().items()}
+        window.queue_wait_s = router.metrics.queue_wait_s[n_wait0:]
+        window.dispatched = [(r.workload, r.kinds, r.m)
+                             for r in rec.records]
     n_compiles = compiles.n - n_comp0
     rid1 = driver.rid
     if trace:
@@ -274,29 +306,51 @@ def serve_window(workload: str, seed: int, seconds: float, *,
         f"{rid1 - rid0} submitted, "
         f"{router.metrics.preemptions - n_pre0} preemptions, "
         f"{window.reschedules} reschedules, {n_compiles} programs built, "
-        f"{backend.structures_in_window} new stage structures, "
+        f"{rec.structures_in_window} new stage structures, "
         f"mean placement {place!r} ms"
         f"{' (under the profiler)' if trace else ''}")
 
     driver.drain()
     done = set(driver.completed)
     failed = (rid1 - rid0) - sum(1 for r in range(rid0, rid1) if r in done)
-    return Served(cfg, router, backend, driver, window, rid1 - rid0, failed,
+    return Served(cfg, router, rec, driver, window, rid1 - rid0, failed,
                   trace_dir)
 
 
+def read_trace(served: Served, device_kind: str, log=print):
+    """Reads a traced run's trace into its ``Window`` (device busy and
+    window seconds, program span totals, device time by program, the
+    device kind, the plug-in's ``work``), deletes the trace, and returns it
+    as an ``xtrace.Trace``."""
+    path = xtrace.find(served.trace_dir)
+    tr = xtrace.read(path)
+    log(f"trace: {os.path.getsize(path)} bytes, "
+        f"{sum(len(v) for v in tr.ops.values())} device ops, "
+        f"{len(tr.spans)} harness spans, {len(tr.program)} program spans")
+    shutil.rmtree(served.trace_dir, ignore_errors=True)
+    w = served.window
+    t0, t1 = tr.window
+    w.window_s = (t1 - t0) * 1e-9
+    w.busy_s = xtrace.busy_ns(tr) * 1e-9
+    totals = program_spans.program_span_totals(tr.program, tr.window)
+    own = program_spans.span_self_seconds(tr.program, tr.window)
+    w.span_totals = {n: (sec, cnt, own.get(n, 0.0))
+                     for n, (cnt, sec) in totals.items()}
+    w.device_programs = xtrace.program_times(tr)
+    w.device_kind = device_kind
+    w.work = getattr(served.recorder.plugin, "work", None)
+    return tr
+
+
 def numbers(served: Served, platform: str) -> dict:
-    """The numbers ``check.py`` compares, for a drained run, and two that
-    it prints only (``_by_shape``, ``_max_abs_gap``)."""
-    router, backend = served.router, served.backend
+    """The numbers ``check.py`` and the plug-in compare, for a drained run,
+    and those printed only (names that start with ``_``)."""
+    router, rec = served.router, served.recorder
     left = len(router.queue) + len(router.engine.inflight)
     out = check.accounting(served.driver.rid, served.driver.completed,
                            router.metrics.dropped, left)
-    b = served.cfg["backend"]
-    out.update(check.outputs(
-        backend.outputs, platform, served.window.batches,
-        operands="bfloat16" if platform == "tpu" else "float32",
-        act_batch=b["act_batch"], act_dim=b["act_dim"]))
+    out.update(check.recorded(rec.records, platform, served.window.batches))
+    out.update(rec.plugin.numbers(rec.records, platform))
     return out
 
 
@@ -317,27 +371,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devices)
     nums = numbers(served, platform)
-    log(f"checked {len(served.backend.outputs)} batch outputs by "
-        f"(stages, m): {nums.pop('_by_shape')}; max_abs_gap "
-        f"{nums.pop('_max_abs_gap')!r}")
-    correct, checks = check.verdict(nums)
+    log(f"checked {len(served.recorder.records)} batch outputs by "
+        f"(stages, m): {nums.pop('_by_shape')}; "
+        + "; ".join(f"{k[1:]} {nums.pop(k)!r}"
+                    for k in [k for k in nums if k.startswith("_")]))
+    correct, checks = check.verdict(nums, served.recorder.plugin.LIMITS)
 
     device = {"platform": platform, "kind": devices[0].device_kind,
               "count": len(devices), "memory_peak_bytes": peak}
     breakdown = None
     if trace:
-        path = xtrace.find(served.trace_dir)
-        tr = xtrace.read(path)
-        log(f"trace: {os.path.getsize(path)} bytes, "
-            f"{sum(len(v) for v in tr.ops.values())} device ops, "
-            f"{len(tr.spans)} harness spans")
-        t0, t1 = tr.window
-        window.window_s = (t1 - t0) * 1e-9
-        window.busy_s = xtrace.busy_ns(tr) * 1e-9
+        tr = read_trace(served, devices[0].device_kind, log=log)
         device.update(busy_s=window.busy_s, window_s=window.window_s)
         breakdown = {"device_ops": xtrace.top_programs(tr),
                      "idle_gaps": xtrace.idle_gaps(tr)}
-        shutil.rmtree(served.trace_dir, ignore_errors=True)
     out = {"correct": correct, "attempted": served.attempted,
            "failed": served.failed,
            "metrics": metrics_for(load_benchmark(), workload, trace, window),

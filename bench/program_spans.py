@@ -5,11 +5,13 @@ numbers they give.
 ``repro.obs.Tracer(profile=True)`` opens a ``jax.profiler.TraceAnnotation``
 named ``dype:<span>`` around each duration span of Router, Engine, the DP
 and the pallas backend, so the spans sit on the profiler's host plane on
-the same clock as the device ops. This module reads them (``read``), sums
-them over the traced window (``program_span_totals``), names each device
+the same clock as the device ops. This module reads them (``read``; a
+traced run's ``xtrace.read`` reads them too), sums them over the traced
+window (``program_span_totals``, ``span_self_seconds``), names each device
 idle gap by the innermost one open over it (``idle_by_program_span``, the
 sweep of ``xtrace.idle_gaps`` over these spans) and turns the totals into
-per-layer numbers (``layer_metrics``).
+per-layer numbers (``layer_metrics``; ``window_metric`` for a reader of a
+traced ``harness.Window``).
 
     python3 bench/program_spans.py --workload <cell> --seed <n> \\
         --seconds <s> --trace <0|1>
@@ -27,30 +29,16 @@ import argparse
 import collections
 import json
 import math
-import shutil
 import sys
 from pathlib import Path
-
-ROOT = Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
-
-PREFIX = "dype:"           # repro.obs.PROFILE_PREFIX
 
 
 def read(path: str) -> list:
     """[(span name without the prefix, start_ns, end_ns)] of the program's
     spans on the ``/host`` planes of the trace at ``path``."""
-    from jax.profiler import ProfileData
+    from bench import xtrace
 
-    out = []
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                out.extend((e.name[len(PREFIX):], e.start_ns,
-                            e.start_ns + e.duration_ns)
-                           for e in line.events
-                           if e.name.startswith(PREFIX))
-    return out
+    return xtrace.read(path).program
 
 
 def program_span_totals(spans: list, window) -> dict:
@@ -64,6 +52,24 @@ def program_span_totals(spans: list, window) -> dict:
             n, sec = acc.get(name, (0, 0.0))
             acc[name] = (n + 1, sec + (e - s) * 1e-9)
     return acc
+
+
+def span_self_seconds(spans: list, window) -> dict:
+    """{name: seconds} of the spans' time inside ``window`` (t0_ns, t1_ns)
+    less that of the spans directly inside them, summed by name. The spans
+    nest: one host thread opens them as context managers."""
+    t0, t1 = window
+    acc: dict = collections.Counter()
+    open_: list = []                   # (end_ns, name), innermost last
+    for name, s, e in sorted(spans, key=lambda x: (x[1], -x[2])):
+        while open_ and open_[-1][0] <= s:
+            open_.pop()
+        d = max(0, min(e, t1) - max(s, t0))
+        acc[name] += d
+        if open_:
+            acc[open_[-1][1]] -= d
+        open_.append((e, name))
+    return {n: v * 1e-9 for n, v in acc.items()}
 
 
 def idle_by_program_span(trace, spans: list, k: int = 20) -> list:
@@ -145,42 +151,19 @@ def layer_metrics(totals: dict, batches: int, launches: int,
     return out
 
 
-def profiled_build(tracer):
-    """A stand-in for ``stack.build`` that puts ``tracer`` in the stack and
-    builds a backend that notes its launches and the router's queue waits
-    when the harness opens and closes the window (``record``); returns
-    (build, marks), ``marks`` filled as {"launches": (at open, at close),
-    "queue_wait": (index at open, at close)}."""
-    from bench import stack
+def window_layer_metrics(w) -> dict:
+    """``layer_metrics`` of a traced ``harness.Window``; {} untraced."""
+    if w.span_totals is None:
+        return {}
+    totals = {n: (cnt, sec) for n, (sec, cnt, _) in w.span_totals.items()}
+    return layer_metrics(totals, w.batches, w.counters.get("launches", 0),
+                         w.queue_wait_s)
 
-    marks: dict = {}
-    build = stack.build
 
-    class Backend(stack.RecordingBackend):
-        router = None
-
-        @property
-        def record(self):
-            return self._record
-
-        @record.setter
-        def record(self, on):
-            self._record = on
-            if self.router is not None:
-                key = 0 if on else 1
-                waits = len(self.router.metrics.queue_wait_s)
-                for name, v in (("launches", self.launches),
-                                ("queue_wait", waits)):
-                    marks.setdefault(name, [None, None])[key] = v
-
-    def profiled(cfg, provisioned_rate, backend=None):
-        backend = backend or Backend(**cfg["backend"])
-        router, backend = build(cfg, provisioned_rate, backend)
-        router.tracer = tracer
-        router.engine.tracer = tracer      # hands it on to the DP, backend
-        backend.router = router
-        return router, backend
-    return profiled, marks
+def window_metric(w, name: str):
+    """``layer_metrics``' number ``name`` for a ``harness.Window``, or
+    None where the window has nothing to read for it."""
+    return window_layer_metrics(w).get(name)
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
@@ -189,46 +172,31 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     the result object (see the module's doc)."""
     import jax
 
-    from bench import check, harness, stack, xtrace
+    from bench import check, harness, xtrace
     from repro.launch.compile_cache import enable as enable_compile_cache
-    from repro.obs import Tracer
 
     t_proc = harness.process_start_wall()
     devices = jax.devices()
     log(f"compile cache {enable_compile_cache()}")
-    build, marks = profiled_build(Tracer(profile=True))
-    plain, stack.build = stack.build, build
-    try:
-        served = harness.serve_window(workload, seed, seconds, trace=trace,
-                                      t_proc=t_proc, log=log)
-    finally:
-        stack.build = plain
+    served = harness.serve_window(workload, seed, seconds, trace=trace,
+                                  profile=True, t_proc=t_proc, log=log)
     window = served.window
     correct, checks = check.verdict(
         {k: v for k, v in harness.numbers(served, devices[0].platform).items()
-         if not k.startswith("_")})
+         if not k.startswith("_")}, served.recorder.plugin.LIMITS)
     out = {"correct": correct, "attempted": served.attempted,
            "failed": served.failed}
     if trace:
-        path = xtrace.find(served.trace_dir)
-        tr = xtrace.read(path)
-        spans = read(path)
-        t0, t1 = tr.window
-        window.window_s = (t1 - t0) * 1e-9
-        window.busy_s = xtrace.busy_ns(tr) * 1e-9
-        totals = program_span_totals(spans, tr.window)
-        (l0, l1), (q0, q1) = marks["launches"], marks["queue_wait"]
-        waits = served.router.metrics.queue_wait_s[q0:q1]
-        out["program"] = layer_metrics(totals, window.batches, l1 - l0,
-                                       waits)
-        out["launches"] = l1 - l0
+        tr = harness.read_trace(served, devices[0].device_kind, log=log)
+        out["program"] = window_layer_metrics(window)
+        out["launches"] = window.counters.get("launches", 0)
         out["batches"] = window.batches
-        out["idle_by_program_span"] = idle_by_program_span(tr, spans)
+        out["idle_by_program_span"] = idle_by_program_span(tr, tr.program)
         out["idle_gaps"] = xtrace.idle_gaps(tr)
-        out["span_totals"] = {n: list(v) for n, v in sorted(totals.items())}
+        out["span_totals"] = {n: list(v)
+                              for n, v in sorted(window.span_totals.items())}
         out["window_s"] = window.window_s
         out["busy_s"] = window.busy_s
-        shutil.rmtree(served.trace_dir, ignore_errors=True)
         log(f"idle by program span: {out['idle_by_program_span']}")
     out["metrics"] = {k: v["value"] for k, v in harness.metrics_for(
         harness.load_benchmark(), workload, trace, window).items()}
@@ -266,4 +234,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    ROOT = Path(__file__).resolve().parents[1]
+    sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
     sys.exit(main())

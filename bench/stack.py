@@ -1,87 +1,130 @@
 """Builds the system under test from a configuration file.
 
 The wiring mirrors ``repro.launch.serve.run_stream`` for ``--stream
---backend pallas`` (no cluster, no fleet, no governor, no tracer), because
-the program has no factory that takes a configuration: DynamicScheduler over
+--backend pallas`` (no cluster, no fleet, no governor), because the program
+has no factory that takes a configuration: DynamicScheduler over
 ``paper_system`` with ``PerfModel()``, the configuration's batcher (or
-tenancy layer), ``LoadWatermarkPolicy``, the pallas backend and
+tenancy layer), ``LoadWatermarkPolicy``, the backend and
 ``Router(async_mode=True)``.
 
-The backend is ``PallasPipelineBackend`` with two additions that leave the
-served arithmetic alone: every stage structure is warmed for each
-microbatch count it can be given as soon as it is first prepared, so that
-nothing compiles once the measured window has begun; and the output of
-every dispatched batch is kept, on the device, for the check after the
-window.
+What a configuration serves on the device comes from its plug-in,
+``plugins/<plugin>.py`` (the configuration's ``"plugin"`` key, ``"proxy"``
+when absent): the ``repro`` workloads, the backend, how a new stage
+structure is warmed and what of each output is kept for the check.
+``Recorder`` wraps the plug-in's backend in place. It leaves the served
+arithmetic alone: it warms every new stage structure as soon as it is
+first prepared, so that nothing compiles once the measured window has
+begun, and while ``record`` is set it notes every batch dispatched.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
-from pathlib import Path
 
 from repro.core import DynamicScheduler, PerfModel, paper_system
-from repro.core.workload import (GraphDataset, gcn_workload,
-                                 swa_transformer_workload)
-from repro.runtime import PallasPipelineBackend
 from repro.serving import LoadWatermarkPolicy, Router, SignatureBatcher
 from repro.tenancy import build_tenancy, parse_tenants
 
-BENCH = Path(__file__).resolve().parent
+from . import files
 
 
 def load_config(name: str) -> dict:
-    return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+    return json.loads(files.find("configs", name, ".json").read_text())
+
+
+def plugin(cfg: dict):
+    """The plug-in module of configuration ``cfg``."""
+    return files.load("plugins", cfg.get("plugin", "proxy"))
 
 
 def workload(name: str, cfg: dict):
     """The configuration's workload ``name`` as a ``repro`` Workload."""
-    w = cfg["workloads"][name]
-    if w["model"] == "gcn":
-        ds = GraphDataset(w["graph"], w["vertices"], w["edges"],
-                          w["features"])
-        return gcn_workload(ds, hidden=w["hidden"], layers=w["layers"])
-    if w["model"] == "swa_t":
-        return swa_transformer_workload(
-            w["seq_len"], w["window"], layers=cfg["swa_layers"], d=w["d"],
-            heads=w["heads"], ffn_mult=w["ffn_mult"])
-    raise ValueError(f"{name}: unknown model {w['model']!r}")
+    return plugin(cfg).workload(name, cfg)
 
 
-class RecordingBackend(PallasPipelineBackend):
-    """``outputs`` holds ``(stage kinds, microbatch count, output)`` for
-    every batch dispatched while ``record`` is set."""
+def stage_kinds(handle) -> tuple:
+    """The kernel kinds of each stage of a handle's schedule."""
+    wl = handle.workload
+    return tuple(tuple(wl[i].kind for i in range(s.i0, s.i1))
+                 for s in handle.schedule.pipeline.stages)
 
-    def __init__(self, **kw):
-        super().__init__(**kw)
+
+@dataclasses.dataclass
+class Record:
+    """One batch dispatched in the window."""
+    workload: str          # the repro Workload's name
+    kinds: tuple           # kernel kinds of each stage
+    m: int                 # inputs in the batch (the input's leading axis)
+    input: object          # the batch input, as dispatched
+    kept: object           # the plug-in's keep(output), on the device
+
+
+class Recorder:
+    """Wraps a backend in place: its ``prepare`` and ``dispatch``.
+
+    The backend's ``submit`` has to run the device work through
+    ``dispatch(handle, x)``, which returns the device arrays of the batch
+    with the output last, and its ``prepare`` has to give a handle of a new
+    stage structure a new ``payload`` object and a handle of a known one
+    the known object. ``prepare`` calls the plug-in's ``warm`` on each new
+    payload; ``dispatch`` appends a ``Record`` to ``records`` while
+    ``record`` is set. ``counters`` are the backend's integer attributes
+    that a traced window reports the change of: ``launches`` and the
+    plug-in's ``COUNTERS``."""
+
+    def __init__(self, backend, plug):
+        self.backend = backend
+        self.plugin = plug
         self.record = False
-        self.outputs: list = []
-        self.kinds: dict = {}          # id(payload) -> stage kinds
+        self.records: list = []
+        self.kinds: dict = {}          # id(payload) -> (payload, kinds)
         self.structures_in_window = 0
+        self._warming = False
+        self.counters = tuple(
+            c for c in ("launches", *getattr(plug, "COUNTERS", ()))
+            if hasattr(backend, c))
+        prepare, dispatch = backend.prepare, backend.dispatch
+        keep = plug.keep
 
-    def prepare(self, schedule, workload, *, epoch: int = 0):
-        import jax
-        before = len(self.prepared)
-        h = super().prepare(schedule, workload, epoch=epoch)
-        if len(self.prepared) > before:
-            (kinds, _), _ = next(reversed(self.prepared.items()))
-            self.kinds[id(h.payload)] = kinds
-            self.structures_in_window += self.record
-            for m in range(1, self.max_micro + 1):
-                jax.block_until_ready(
-                    super().dispatch(h, self.microbatches(m)))
-        return h
+        def recording_prepare(schedule, workload, *, epoch: int = 0):
+            h = prepare(schedule, workload, epoch=epoch)
+            if id(h.payload) not in self.kinds:
+                self.kinds[id(h.payload)] = (h.payload, stage_kinds(h))
+                self.structures_in_window += self.record
+                self._warming = True
+                try:
+                    plug.warm(backend, h)
+                finally:
+                    self._warming = False
+            return h
 
-    def dispatch(self, handle, micro):
-        outs = super().dispatch(handle, micro)
-        if self.record:
-            self.outputs.append((self.kinds[id(handle.payload)],
-                                 micro.shape[0], outs[-1]))
-        return outs
+        def recording_dispatch(handle, x):
+            outs = dispatch(handle, x)
+            if self.record and not self._warming:
+                self.records.append(Record(
+                    handle.workload.name, self.kinds[id(handle.payload)][1],
+                    x.shape[0], x, keep(outs[-1])))
+            return outs
+
+        backend.prepare = recording_prepare
+        backend.dispatch = recording_dispatch
+
+    @property
+    def structures(self) -> int:
+        """Stage structures prepared (and warmed) so far."""
+        return len(self.kinds)
+
+    def counts(self) -> dict:
+        return {c: getattr(self.backend, c) for c in self.counters}
 
 
-def build(cfg: dict, provisioned_rate: float, backend=None):
-    """(router, backend) for configuration ``cfg``; ``backend`` replaces
-    the recording pallas backend (``knee.py`` passes the analytic one)."""
+def build(cfg: dict, provisioned_rate: float, backend=None, *,
+          tracer=None):
+    """(router, served) for configuration ``cfg``. Without ``backend`` the
+    plug-in's backend serves, wrapped by a ``Recorder``, which is
+    ``served``; a given ``backend`` (``knee.py`` passes the analytic one)
+    serves as it is and is ``served``. ``tracer`` goes into the Router,
+    which hands it on to the Engine, the DP and the backend."""
     system = paper_system(cfg["interconnect"])
     pool = {dev.name: n for dev, n in system.pools}
     if pool != cfg["pool"]:
@@ -100,14 +143,17 @@ def build(cfg: dict, provisioned_rate: float, backend=None):
         batcher = SignatureBatcher(max_batch=b["max_batch"],
                                    max_wait=b["max_wait_s"])
     p = cfg["policy"]
+    served = backend
     if backend is None:
-        backend = RecordingBackend(**cfg["backend"])
+        plug = plugin(cfg)
+        backend = plug.backend(cfg)
+        served = Recorder(backend, plug)
     router = Router(
         dyn, batcher=batcher,
         policy=LoadWatermarkPolicy(low=p["low"], high=p["high"],
                                    window=p["window_s"],
                                    cooldown=p["cooldown_s"]),
         backend=backend, max_cells=cfg["max_cells"],
-        async_mode=cfg["async_dispatch"], tenancy=manager)
+        async_mode=cfg["async_dispatch"], tenancy=manager, tracer=tracer)
     router.provisioned_capacity = provisioned_rate
-    return router, backend
+    return router, served

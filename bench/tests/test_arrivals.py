@@ -1,4 +1,5 @@
 import collections
+import copy
 
 import pytest
 
@@ -50,10 +51,10 @@ def test_diurnal_rate_and_shares():
 
 
 def test_bursts_keep_the_load_and_move_with_the_seed():
+    # every seed has the burst at start_s; the arrivals move with the seed
     spec = arrivals.load_traffic("bursty-llm-tenants")
     b = spec["arrivals"]["bursts"]
     ticks_per = round(b["period_s"] / spec["tick_s"])
-    starts = []
     for seed in (BIG, BIG + 1):
         s = arrivals.Stream(spec, seed)
         rates = [s.rate(i * spec["tick_s"]) for i in range(ticks_per * 5)]
@@ -63,10 +64,56 @@ def test_bursts_keep_the_load_and_move_with_the_seed():
             assert sum(r > s.base for r in per) == \
                 round(b["len_s"] / spec["tick_s"])
             assert max(per) == pytest.approx(b["mult"] * s.base)
+            assert per.index(max(per)) == round(b["start_s"] / spec["tick_s"])
         assert sum(rates) * spec["tick_s"] / (5 * b["period_s"]) == \
             pytest.approx(spec["arrivals"]["mean_rate"], rel=0.01)
-        starts.append(rates.index(max(rates)))
-    assert starts[0] != starts[1]
+    a, c = ticks("bursty-llm-tenants", BIG, ticks_per), \
+        ticks("bursty-llm-tenants", BIG + 1, ticks_per)
+    assert [x.t for x in a] != [x.t for x in c]
+
+
+def _stretches(got, b) -> list:
+    """Arrivals cut at the burst edges: (count, names, tenants) a stretch."""
+    p, s0, s1 = b["period_s"], b["start_s"], b["start_s"] + b["len_s"]
+    out = collections.defaultdict(list)
+    for a in got:
+        k, off = divmod(a.t, p)
+        out[(k, (off >= s0) + (off >= s1))].append(a)
+    return [(len(v), collections.Counter(a.name for a in v),
+             collections.Counter(a.tenant for a in v))
+            for _, v in sorted(out.items())]
+
+
+def test_bursty_stretches_hold_the_same_work_for_every_seed():
+    spec = arrivals.load_traffic("bursty-llm-tenants")
+    b = spec["arrivals"]["bursts"]
+    n_ticks = round(3 * b["period_s"] / spec["tick_s"])
+    a, c = ticks("bursty-llm-tenants", BIG, n_ticks), \
+        ticks("bursty-llm-tenants", BIG + 1, n_ticks)
+    # the same counts, lengths and tenants in every stretch, in another
+    # order and at other times
+    assert _stretches(a, b) == _stretches(c, b)
+    assert [x.name for x in a] != [x.name for x in c]
+    base = spec["arrivals"]["mean_rate"] / (
+        1 + (b["mult"] - 1) * b["len_s"] / b["period_s"])
+    counts = [n for n, _, _ in _stretches(a, b)]
+    assert len(counts) == 9
+    assert counts[:3] == [round(base * b["start_s"]),
+                          round(base * b["mult"] * b["len_s"]),
+                          round(base * (b["period_s"] - b["start_s"]
+                                        - b["len_s"]))]
+    assert len(a) == pytest.approx(3 * b["period_s"]
+                                   * spec["arrivals"]["mean_rate"], abs=3)
+    burst = _stretches(a, b)[1]
+    assert burst[2]["gold"] == round(0.25 * burst[0])
+    assert burst[1]["llm-swa-8192"] == round(0.12 * burst[0])
+
+
+def test_swing_and_bursts_together_are_refused():
+    spec = copy.deepcopy(arrivals.load_traffic("bursty-llm-tenants"))
+    spec["arrivals"]["swing"] = {"trough_share": 0.5, "period_s": 60.0}
+    with pytest.raises(ValueError):
+        arrivals.Stream(spec, 1)
 
 
 def test_llm_shares_and_tenants():

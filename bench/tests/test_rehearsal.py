@@ -34,15 +34,48 @@ def test_result_line(cell, no_compile_cache):
     assert all(c["value"] <= c["limit"] for c in out["checks"].values())
 
 
-def test_traced_result_line(no_compile_cache):
-    cell = CELLS[0]
+#: the per-layer metrics read from the program's spans and counters
+PROGRAM = {"batch_form_ms", "admit_ms", "micro_build_ms", "launch_us",
+           "launches_per_batch", "queue_wait_wall_p95_ms"}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_traced_result_line(cell, no_compile_cache, monkeypatch):
+    seen = []
+    metrics_for = harness.metrics_for
+
+    def keep_window(bench, cell, trace, w):
+        seen.append(w)
+        return metrics_for(bench, cell, trace, w)
+    monkeypatch.setattr(harness, "metrics_for", keep_window)
     out = harness.run_cell(cell, SEED, 0.5, True, log=_log)
     assert out["correct"] is True
-    # the CPU has no device plane: the counters' metrics only
-    assert {"place_ms", "batch_occupancy", "reschedules_per_kreq"} <= \
-        set(out["metrics"])
+    # the CPU has no device plane: the counters' and spans' metrics only
+    tenancy = cell == "llm-tenants-bursty"
+    want = {"place_ms", "batch_occupancy", "reschedules_per_kreq"} | \
+        PROGRAM | ({"preempt_pass_ms"} if tenancy else set())
+    assert set(out["metrics"]) == want
+    assert all(m["value"] > 0 for m in out["metrics"].values())
     assert out["device"]["window_s"] > 0
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
+    w, = seen
+    assert {"router.step", "backend.dispatch", "engine.admit"} <= \
+        set(w.span_totals)
+    for sec, n, own in w.span_totals.values():
+        assert n > 0 and 0 <= own <= sec + 1e-12
+    assert w.counters["launches"] >= w.batches > 0
+    assert w.queue_wait_s and len(w.dispatched) == w.batches
+    assert w.device_programs == {} and w.device_kind == "cpu"
+    assert w.work is None          # the proxy has no work hook
+
+
+def test_untraced_window_carries_no_trace_fields(no_compile_cache):
+    served = harness.serve_window(CELLS[0], SEED, 0.3, log=_log)
+    w = served.window
+    assert served.router.tracer.timing is False
+    assert (w.span_totals, w.counters, w.queue_wait_s, w.device_programs,
+            w.device_kind, w.dispatched, w.work) == (None,) * 7
+    assert served.recorder.records and w.batches > 0
 
 
 def test_no_accelerator_no_result(tmp_path):

@@ -6,8 +6,10 @@ Device operations are the events of the ``XLA Ops`` line of each
 ``/device:TPU:<n>`` plane, and the programs they belong to those of its
 ``XLA Modules`` line. Host spans are the harness's own
 ``jax.profiler.TraceAnnotation`` events on the ``/host:CPU`` plane, whose
-names start with ``HOST_PREFIX``. Both are read on the profiler's one clock,
-in nanoseconds. The traced window runs from the first harness span's start
+names start with ``HOST_PREFIX``, and the program's own duration spans,
+whose names start with ``PROGRAM_PREFIX`` (``Tracer(profile=True)``,
+read by ``program_spans.py``). All are read on the profiler's one clock, in
+nanoseconds. The traced window runs from the first harness span's start
 to the last one's end.
 """
 from __future__ import annotations
@@ -18,6 +20,7 @@ import glob
 import os
 
 HOST_PREFIX = "bench:"
+PROGRAM_PREFIX = "dype:"        # repro.obs.PROFILE_PREFIX
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -28,6 +31,8 @@ class Trace:
     ops: dict            # device plane -> [(name, start_ns, end_ns)]
     modules: dict        # device plane -> [(name, start_ns, end_ns)]
     spans: list          # [(name, start_ns, end_ns)] harness spans
+    # [(name without the prefix, start_ns, end_ns)] program spans
+    program: list = dataclasses.field(default_factory=list)
 
     @property
     def window(self) -> tuple[float, float]:
@@ -47,7 +52,7 @@ def read(path: str) -> Trace:
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
-    ops, modules, spans = {}, {}, []
+    ops, modules, spans, program = {}, {}, [], []
     for plane in pd.planes:
         if plane.name.startswith(DEVICE_PLANE):
             for line in plane.lines:
@@ -57,11 +62,16 @@ def read(path: str) -> Trace:
                         (e.name, e.start_ns, e.start_ns + e.duration_ns)
                         for e in line.events)
         elif plane.name.startswith("/host:"):
+            n = len(PROGRAM_PREFIX)
             for line in plane.lines:
-                spans.extend((e.name, e.start_ns, e.start_ns + e.duration_ns)
-                             for e in line.events
-                             if e.name.startswith(HOST_PREFIX))
-    return Trace(ops, modules, spans)
+                for e in line.events:
+                    if e.name.startswith(HOST_PREFIX):
+                        spans.append((e.name, e.start_ns,
+                                      e.start_ns + e.duration_ns))
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        program.append((e.name[n:], e.start_ns,
+                                        e.start_ns + e.duration_ns))
+    return Trace(ops, modules, spans, program)
 
 
 def merged(intervals, t0: float, t1: float) -> list[tuple[float, float]]:
@@ -88,18 +98,26 @@ def busy_ns(trace: Trace) -> float:
     return tot / len(trace.ops)
 
 
-def top_programs(trace: Trace, k: int = 10) -> list:
-    """[name, seconds] of the k device programs (jitted computations, named
-    with their fingerprint) with most device time in the window, summed
-    over their calls and averaged over the devices."""
+def program_times(trace: Trace) -> dict:
+    """{name: (seconds, calls)} of every device program (a jitted
+    computation, named with its fingerprint) run in the window: its device
+    time summed over its calls, both averaged over the devices."""
     t0, t1 = trace.window
-    acc = collections.Counter()
+    acc, calls = collections.Counter(), collections.Counter()
     for evs in trace.modules.values():
         for name, s, e in evs:
             s, e = max(s, t0), min(e, t1)
             if e > s:
                 acc[name] += (e - s) * 1e-9 / len(trace.modules)
-    return [[n, v] for n, v in acc.most_common(k)]
+                calls[name] += 1
+    return {n: (v, calls[n] / len(trace.modules))
+            for n, v in acc.most_common()}
+
+
+def top_programs(trace: Trace, k: int = 10) -> list:
+    """[name, seconds] of the k device programs with most device time in
+    the window (``program_times``)."""
+    return [[n, v] for n, (v, _) in list(program_times(trace).items())[:k]]
 
 
 def idle_gaps(trace: Trace, k: int = 10) -> list:
