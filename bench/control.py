@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Readings that the proxy plug-in's limit is set from, in one process.
+"""Readings that a plug-in's limits are set from, in one process.
 
     python3 bench/control.py --workload <cell> --seeds 12 --control-seeds 3 \\
         --seconds 10 --first-seed <n>
@@ -7,13 +7,14 @@
 For each seed: the cell's stack, its warm-up and a window of ``--seconds``
 at the cell's own load, exactly as a run makes them; then the program's
 numbers after the drain. For the first ``--control-seeds`` seeds also the
-control's: the reference chain in bfloat16 on the device, the nearest
-precision below the configuration's, put in the program's place on the same
-batches (a hook of the proxy plug-in, ``plugins/proxy.py``, which both
-cells use). One JSON line per seed, then the summary: the lower reading
-(the largest the program gives) and the upper (the smallest the control
-gives) of each gap. Benchmark runs never run the control. Exits with 2 unless JAX's
-first device is a TPU.
+control's: the plug-in's ``control(records, platform)`` hook, its numbers
+with the device work redone one precision below the configuration's, put
+in the program's place on the same batches. One JSON line per seed, then
+the summary: for each number of the plug-in's ``LIMITS`` and each printed
+one (``_`` names) that the control gives, the lower reading (the largest
+the program gives) and the upper (the smallest the control gives).
+Benchmark runs never run the control. Exits with 2 unless JAX's first
+device is a TPU.
 """
 from __future__ import annotations
 
@@ -39,19 +40,22 @@ def readings(workload: str, seed: int, seconds: float, *,
     nums = harness.numbers(served, platform)
     nums.pop("_by_shape")
     out.update(nums)
-    proxy = served.recorder.plugin   # the control is the proxy's hook
-    records = served.recorder.records
-    recs = proxy.host_records(records)
-    gaps = {}
-    if records:
-        _, gaps["act_batch"], gaps["act_dim"] = records[0].input.shape
-    out["vs_float32"] = proxy.output_gaps(recs, operands="float32", **gaps)
     if control:
-        out["control"] = proxy.output_gaps(
-            recs, operands=proxy.operands(platform), control=True, **gaps)
-        out["control_vs_float32"] = proxy.output_gaps(
-            recs, operands="float32", control=True, **gaps)
+        out["control"] = served.recorder.plugin.control(
+            served.recorder.records, platform)
     return out
+
+
+def summary(rows: list, limits: dict) -> dict:
+    """{name: (lower, upper)} of each number of ``limits`` and each printed
+    one (``_`` names) that the control gives: the largest reading of the
+    program over ``rows`` and the smallest of the control (None without
+    a control reading)."""
+    ctrl = [r["control"] for r in rows if "control" in r]
+    printed = [k for k in (ctrl[0] if ctrl else {}) if k.startswith("_")]
+    return {k: (max(r[k] for r in rows), min((c[k] for c in ctrl),
+                                             default=None))
+            for k in [*limits, *printed]}
 
 
 def main(argv=None) -> int:
@@ -67,24 +71,23 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("control: needs a TPU", file=sys.stderr)
         return 2
+    from bench import harness, stack
     from repro.launch.compile_cache import enable
     enable()
+    cell = next(c for c in harness.load_benchmark()["workloads"]
+                if c["name"] == args.workload)
+    limits = stack.plugin(stack.load_config(cell["config"])).LIMITS
     rows = []
     for i in range(args.seeds):
         r = readings(args.workload, args.first_seed + i, args.seconds,
                      control=i < args.control_seeds)
         rows.append(r)
         print(json.dumps(r), flush=True)
-    lower = max(r["worst_answer_gap"] for r in rows)
-    ctrl = [r["control"]["worst_answer_gap"] for r in rows if "control" in r]
     print(json.dumps({
         "workload": args.workload, "seeds": len(rows),
-        "lower_worst_answer_gap": lower,
-        "upper_worst_answer_gap": min(ctrl) if ctrl else None,
-        "lower_max_abs_gap": max(r["_max_abs_gap"] for r in rows),
-        "upper_max_abs_gap": min((r["control"]["max_abs_gap"]
-                                  for r in rows if "control" in r),
-                                 default=None)}), flush=True)
+        **{f"{end}_{k.lstrip('_')}": v
+           for k, pair in summary(rows, limits).items()
+           for end, v in zip(("lower", "upper"), pair)}}), flush=True)
     return 0
 
 

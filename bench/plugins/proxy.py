@@ -3,8 +3,9 @@ chain: GCN and SWA-T signatures for the DP, an (m <= 8, 8, 16) float32
 activation through one jit a stage on the chip.
 
 The hooks every plug-in provides (``PERF.md`` section 4 lists them):
-``workload``, ``backend``, ``warm``, ``keep``, ``numbers`` and ``LIMITS``;
-this one has no ``work``, since its stages do no model's arithmetic.
+``workload``, ``backend``, ``warm``, ``keep``, ``numbers``, ``control`` and
+``LIMITS``; this one has no ``work``, since its stages do no model's
+arithmetic, and one fault of its own in ``FAULTS``.
 """
 from __future__ import annotations
 
@@ -97,14 +98,37 @@ def host_records(records) -> list:
     return [(r.kinds, r.m, h) for r, h in zip(records, host)]
 
 
-def numbers(records, platform: str) -> dict:
-    """``worst_answer_gap`` over the recorded batches (``stack.Record``),
-    against the reference chain at the platform's matmul precision; the
-    (8, 16) activation shape is the recorded input's."""
+def _gaps(records, platform: str, control: bool) -> dict:
     if not records:
         return {"worst_answer_gap": 0.0, "_max_abs_gap": 0.0}
     _, act_batch, act_dim = records[0].input.shape
     gaps = output_gaps(host_records(records), operands=operands(platform),
-                       act_batch=act_batch, act_dim=act_dim)
+                       act_batch=act_batch, act_dim=act_dim, control=control)
     return {"worst_answer_gap": gaps["worst_answer_gap"],
             "_max_abs_gap": gaps["max_abs_gap"]}
+
+
+def numbers(records, platform: str) -> dict:
+    """``worst_answer_gap`` over the recorded batches (``stack.Record``),
+    against the reference chain at the platform's matmul precision; the
+    (8, 16) activation shape is the recorded input's."""
+    return _gaps(records, platform, control=False)
+
+
+def control(records, platform: str) -> dict:
+    """``numbers`` with the chain redone in bfloat16 on the device, the
+    nearest precision below the configuration's, in the program's place on
+    the same batches. Benchmark runs never call it (``bench/control.py``)."""
+    return _gaps(records, platform, control=True)
+
+
+def _state_unchanged(monkeypatch) -> None:
+    """Every stage returns its activation unchanged."""
+    from repro.runtime import PallasPipelineBackend
+    monkeypatch.setattr(PallasPipelineBackend, "_stage_fn",
+                        lambda self, kinds: (lambda p, x: x))
+
+
+# fault name -> a function of pytest's monkeypatch that plants it, for
+# bench/tests/test_faults.py beside the faults every plug-in can have
+FAULTS = {"state_unchanged": _state_unchanged}

@@ -1,6 +1,7 @@
 """Plug-in of the toy configuration: a backend whose every stage doubles its
 input, one jitted program a stage. It joins the benchmark by new files
-alone (``bench/tests/test_plugins.py``)."""
+alone: the per-cell tests run it beside ``BENCHMARK.json``'s cells
+(``bench/tests/cells.py``)."""
 from __future__ import annotations
 
 import dataclasses
@@ -67,8 +68,9 @@ def backend(cfg: dict):
 
         def inputs(self, n: int):
             m = max(1, min(n, self.max_inputs))
+            # thirds, which bfloat16 cannot hold: the control has to fail
             return jnp.arange(m * self.width, dtype=jnp.float32).reshape(
-                m, self.width)
+                m, self.width) / 3
 
         def dispatch(self, handle, x) -> tuple:
             with self.tracer.span("backend", "toy.dispatch", 0.0):
@@ -110,14 +112,42 @@ def work(workload: str, kinds: tuple, m: int) -> tuple[float, float]:
     return float(n), 8.0 * n
 
 
-def numbers(records, platform: str) -> dict:
-    """``toy_gap``: the largest absolute difference between a kept output
-    and the reference over its recorded input."""
+def _gap(records, outputs) -> dict:
+    """``toy_gap``: the largest absolute difference between an output and
+    the reference over its recorded input."""
     import jax
 
-    host = jax.device_get([(r.input, r.kept) for r in records])
+    host = jax.device_get([(r.input, out) for r, out in zip(records,
+                                                            outputs)])
     gap = 0.0
     for r, (x, got) in zip(records, host):
         want = reference.chain(np.asarray(x), len(r.kinds))
-        gap = max(gap, float(np.abs(np.asarray(got) - want).max()))
+        gap = max(gap, float(np.abs(np.asarray(got, np.float32)
+                                    - want).max()))
     return {"toy_gap": gap}
+
+
+def numbers(records, platform: str) -> dict:
+    """``toy_gap`` of the kept outputs."""
+    return _gap(records, [r.kept for r in records])
+
+
+def control(records, platform: str) -> dict:
+    """``toy_gap`` of the chain redone in bfloat16 on the device."""
+    import jax.numpy as jnp
+
+    outs = []
+    for r in records:
+        x = r.input.astype(jnp.bfloat16)
+        for _ in r.kinds:
+            x = x + x
+        outs.append(x)
+    return _gap(records, outs)
+
+
+def _state_unchanged(monkeypatch) -> None:
+    """Every stage returns its input unchanged."""
+    monkeypatch.setitem(globals(), "toy_stage", lambda x: x)
+
+
+FAULTS = {"state_unchanged": _state_unchanged}

@@ -6,8 +6,10 @@ import re
 import pytest
 
 from bench import harness
+from bench.tests import cells
 
 B = harness.load_benchmark()
+TOY = json.loads((cells.TOY / "BENCHMARK.json").read_text())
 BENCH = harness.BENCH
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 UNIT = re.compile(r"[A-Za-z0-9_/%.-]{1,16}")
@@ -63,7 +65,8 @@ def test_cell_files(w):
     assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
 
 
-@pytest.mark.parametrize("c", B["configs"], ids=lambda c: c["name"])
+@pytest.mark.parametrize("c", B["configs"] + TOY["configs"],
+                         ids=lambda c: c["name"])
 def test_config_file(c):
     cfg = json.loads((harness.ROOT / c["file"]).read_text())
     assert cfg["name"] == c["name"]
@@ -71,6 +74,8 @@ def test_config_file(c):
     assert cfg["reduced"] == c["reduced"]
     for k in c["reduced"]:
         assert k in cfg and k in cfg["published"]
+    # a CPU preset changes sizes of the configuration, never its plug-in
+    assert set(cfg.get("cpu", {})) <= set(cfg) - {"cpu", "plugin", "name"}
 
 
 def test_command_and_paths():

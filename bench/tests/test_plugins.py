@@ -1,15 +1,15 @@
 """Configurations as plug-ins: the proxy plug-in compares as the check did
 before it moved there, and a toy configuration made only of new files
 under ``tests/data/toy`` is served, checked and read by the harness."""
+import json
 import sys
-from pathlib import Path
 
 import numpy as np
 import pytest
 
 from bench import files, harness, peaks, reference, stack
+from bench.tests import cells
 
-TOY = Path(__file__).parent / "data" / "toy"
 SEED = 2**31 + 41
 
 
@@ -50,12 +50,33 @@ def test_proxy_numbers_are_the_checks_before(platform):
     assert proxy.LIMITS == {"worst_answer_gap": 3.0e-4}
 
 
-def test_every_cell_has_its_plugins_limits():
-    for w in harness.load_benchmark()["workloads"]:
-        plug = stack.plugin(stack.load_config(w["config"]))
-        assert plug.LIMITS and not set(plug.LIMITS) & set(harness.check.LIMITS)
-        for hook in ("workload", "backend", "warm", "keep", "numbers"):
-            assert callable(getattr(plug, hook)), hook
+# what bench/control.py's call of output_gaps(control=True) gave on these
+# records before the control became the plug-in's hook
+BEFORE_CONTROL = {"cpu": (0.0012022799346596003, 0.004126518964767456),
+                  "tpu": (0.0011323501821607351, 0.004058748483657837)}
+
+
+@pytest.mark.parametrize("platform", sorted(BEFORE_CONTROL))
+def test_proxy_control_is_the_control_before(platform):
+    proxy = stack.plugin({})
+    records = _proxy_records()
+    got = proxy.control(records, platform)
+    old = proxy.output_gaps(proxy.host_records(records),
+                            operands=proxy.operands(platform), control=True)
+    assert (got["worst_answer_gap"], got["_max_abs_gap"]) == \
+        (old["worst_answer_gap"], old["max_abs_gap"]) == \
+        BEFORE_CONTROL[platform]
+
+
+@pytest.mark.parametrize("c", cells.ALL, ids=lambda c: c.id)
+def test_every_cell_has_its_plugins_limits(c):
+    plug = cells.plugin_of(c)
+    assert plug.LIMITS and not set(plug.LIMITS) & set(harness.check.LIMITS)
+    for hook in ("workload", "backend", "warm", "keep", "numbers",
+                 "control"):
+        assert callable(getattr(plug, hook)), hook
+    faults = getattr(plug, "FAULTS", {})
+    assert all(isinstance(k, str) and callable(f) for k, f in faults.items())
 
 
 # ---------------------------------------------------------------------------
@@ -63,11 +84,30 @@ def test_every_cell_has_its_plugins_limits():
 # ---------------------------------------------------------------------------
 @pytest.fixture
 def toy(monkeypatch, no_compile_cache):
-    """The harness pointed at ``tests/data/toy`` first; the toy plug-in."""
-    for kind, dirs in files.DIRS.items():
-        monkeypatch.setitem(files.DIRS, kind, [TOY / kind, *dirs])
-    monkeypatch.setattr(files, "BENCHMARK", TOY / "BENCHMARK.json")
+    """The harness pointed at ``tests/data/toy`` first, with the toy's
+    ``"cpu"`` preset, as the per-cell tests run it; the toy plug-in."""
+    cells.point(monkeypatch, cells.TOY_CELLS[0], cpu=True)
     return files.load("plugins", "toy_doubler")
+
+
+def test_harness_takes_the_configuration_as_written(monkeypatch,
+                                                    no_compile_cache):
+    """``bench/run.py``, ``knee.py`` and ``control.py`` load configurations
+    through ``stack.load_config``: the ``"cpu"`` preset is not applied
+    there, only by the tests' ``cells.point(..., cpu=True)``."""
+    toy_cell = cells.TOY_CELLS[0]
+    cells.point(monkeypatch, toy_cell)
+    written = json.loads(files.find("configs", "toy-doubler",
+                                    ".json").read_text())
+    want = written["backend"]["max_inputs"]
+    assert want != written["cpu"]["backend"]["max_inputs"]
+    served = harness.serve_window(toy_cell.name, SEED, 0.2, log=_log)
+    assert served.cfg == written
+    assert served.recorder.backend.max_inputs == want
+    cells.point(monkeypatch, toy_cell, cpu=True)
+    served = harness.serve_window(toy_cell.name, SEED, 0.2, log=_log)
+    assert served.recorder.backend.max_inputs == \
+        written["cpu"]["backend"]["max_inputs"]
 
 
 def test_toy_is_served_checked_and_read(toy, monkeypatch):
