@@ -26,16 +26,32 @@ from .scheduler import ScheduleResult, Scheduler
 from .workload import Workload
 
 
-def signature(wl: Workload, *, log_quant: float = 0.25) -> tuple:
+LOG_QUANT = 0.25            # signature's quantization step, in decades
+signatures_computed = 0     # signature tuples built, process-wide
+
+
+def signature(wl: Workload) -> tuple:
     """Quantized characteristic signature: kernel kinds + log-quantized dims.
-    Two workloads with the same signature share a schedule."""
+    Two workloads with the same signature share a schedule.
+
+    A ``Workload`` is frozen, so its signature is computed once and kept in
+    the instance ``__dict__`` (outside the dataclass fields: ``==``,
+    ``hash``, ``repr`` and ``dataclasses.replace`` ignore it, and a
+    replaced copy computes afresh)."""
+    global signatures_computed
+    kept = wl.__dict__.get("_signature")
+    if kept is not None:
+        return kept
+    signatures_computed += 1
     sig = []
     for k in wl:
         dims = (k.M, k.K, k.N, k.nnz, k.seq_len, k.w)
-        q = tuple(0 if d <= 0 else round(math.log10(d) / log_quant)
+        q = tuple(0 if d <= 0 else round(math.log10(d) / LOG_QUANT)
                   for d in dims)
         sig.append((k.kind,) + q)
-    return tuple(sig)
+    sig = tuple(sig)
+    wl.__dict__["_signature"] = sig
+    return sig
 
 
 @dataclasses.dataclass

@@ -47,19 +47,16 @@ class ArrivalForecaster:
         self._sig_wl: dict = {}            # signature -> sample workload
 
     # -- ingest ----------------------------------------------------------------
-    def observe(self, t: float, wl=None, sig=None) -> None:
-        """One arrival at simulated time ``t``; ``wl`` (plus its
-        precomputed ``sig``nature, when the caller has one) feeds the
+    def observe(self, t: float, wl=None) -> None:
+        """One arrival at simulated time ``t``; ``wl`` feeds the
         per-signature heat ranking for cell pre-warming."""
         self._roll(t)
         self._n += 1
-        if sig is None and wl is not None:
+        if wl is not None:
             from ..core.dynamic import signature
             sig = signature(wl)
-        if sig is not None:
             self._sig_n[sig] = self._sig_n.get(sig, 0) + 1
-            if wl is not None:
-                self._sig_wl.setdefault(sig, wl)
+            self._sig_wl.setdefault(sig, wl)
 
     def _roll(self, now: float) -> None:
         """Close every bucket the clock has passed (empty ones included —

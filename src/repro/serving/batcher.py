@@ -41,19 +41,11 @@ class SignatureBatcher:
     def __init__(self, max_batch: int = 16, max_wait: float = 0.25):
         self.max_batch = max_batch
         self.max_wait = max_wait
-        self._sig_cache: dict[int, tuple] = {}   # rid -> signature
-
-    def _sig(self, req: Request) -> tuple:
-        s = self._sig_cache.get(req.rid)
-        if s is None:
-            s = signature(req.wl)
-            self._sig_cache[req.rid] = s
-        return s
 
     def groups(self, queue: RequestQueue) -> dict[tuple, list[Request]]:
         by_sig: dict[tuple, list[Request]] = {}
         for r in queue:
-            by_sig.setdefault(self._sig(r), []).append(r)
+            by_sig.setdefault(signature(r.wl), []).append(r)
         return by_sig
 
     def next_batch(self, queue: RequestQueue, now: float,
@@ -82,15 +74,8 @@ class SignatureBatcher:
                 continue
             picked = grp[:self.max_batch]
             queue.take(picked)
-            self.forget(picked)
             return Batch(sig, picked)
         return None
-
-    def forget(self, reqs) -> None:
-        """Evict signature-cache entries for requests leaving the queue
-        (dispatched OR expired) — the cache must not outlive the backlog."""
-        for r in reqs:
-            self._sig_cache.pop(r.rid, None)
 
     def drain(self, queue: RequestQueue, now: float) -> list[Batch]:
         """All dispatchable batches this cycle (used when the executor is

@@ -202,7 +202,6 @@ class Router:
             # requests to make room: account them as drops (they were
             # counted admitted)
             for victim in self.queue.take_displaced():
-                self.batcher.forget([victim])
                 self.metrics.record_drop(tenant=victim.tenant)
                 if tr.enabled:
                     tr.instant(f"r{victim.rid}", "displace", now,
@@ -389,7 +388,6 @@ class Router:
         if dead:
             for req in dead:
                 self.metrics.record_drop(tenant=req.tenant)
-            self.batcher.forget(dead)
             if self.tracer.enabled:
                 for req in dead:
                     self.tracer.instant(f"r{req.rid}", "expire", now)
@@ -437,7 +435,6 @@ class Router:
                 if not self.engine.preempt(victim, now):
                     continue           # unsafe to cancel; try the next
                 self.queue.requeue(batch.requests)
-                self.batcher.forget(batch.requests)
                 self.metrics.record_preempt(
                     len(batch.requests), t0=victim.t0, now=now,
                     tenant=batch.requests[0].tenant)

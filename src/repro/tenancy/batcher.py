@@ -20,6 +20,7 @@ blocked only by executor availability. Its *actual* priority is reported
 """
 from __future__ import annotations
 
+from repro.core.dynamic import signature
 from repro.serving.batcher import Batch, SignatureBatcher
 
 from .wfq import TenantManager
@@ -34,7 +35,7 @@ class TenantBatcher(SignatureBatcher):
     def tenant_groups(self, queue):
         by_key: dict[tuple, list] = {}
         for req in queue:
-            by_key.setdefault((req.tenant, self._sig(req)), []).append(req)
+            by_key.setdefault((req.tenant, signature(req.wl)), []).append(req)
         return by_key
 
     def _order_key(self, now: float):
@@ -64,7 +65,6 @@ class TenantBatcher(SignatureBatcher):
                 continue
             picked = grp[: self.max_batch]
             queue.take(picked)
-            self.forget(picked)
             self.manager.charge(tenant, len(picked))
             return Batch(sig, picked)
         return None

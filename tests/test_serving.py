@@ -189,15 +189,14 @@ def test_router_monitor_follows_schedule_identity():
         [s.total for s in r.dyn.active.pipeline.stages])
 
 
-def test_batcher_sig_cache_evicted_on_expiry():
+def test_router_expires_and_drops_queued_request():
     r = fresh_router()
     r.submit(req(0, WL_A, 0.0, deadline=1.0), 0.0)
-    r.step(0.1)                 # underfull + young: held, cache populated
+    r.step(0.1)                 # underfull + young: held
     assert len(r.queue) == 1
     r.step(2.0)                 # deadline passed while queued
     assert len(r.queue) == 0
     assert r.metrics.dropped == 1
-    assert r.batcher._sig_cache == {}
 
 
 # ---------------------------------------------------------------------------
